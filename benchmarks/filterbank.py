@@ -171,7 +171,7 @@ def _legacy_fir_bank(x, h, *, wl, vbl, kind=0, shift=0, bc=8, bt=512,
         out_specs=pl.BlockSpec((bc, bt), lambda c, t: (c, t)),
         out_shape=jax.ShapeDtypeStruct((nc * bc, nt * bt), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bc, taps - 1), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, hp)

@@ -18,7 +18,9 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get_arch
-from repro.launch.mesh import HW
+from repro.launch.mesh import PRODUCTION_KIND, peaks
+
+HW = peaks(PRODUCTION_KIND)   # the dry-run compiles for the production mesh
 
 RESULTS = os.path.join(os.path.dirname(__file__), "dryrun_results.json")
 

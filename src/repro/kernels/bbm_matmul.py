@@ -35,8 +35,9 @@ TPU adaptation notes (this is the paper's multiplier *as a TPU kernel*):
     callers whose weights are long-lived.
   * The Booth row loop (wl/2 iterations) is unrolled at trace time; each row
     materializes one (bm, bk, bn) int32 tile in VMEM.  With the default
-    64x64x64 blocking that is 1 MiB live — comfortably inside the ~16 MiB
-    VMEM budget together with the x/w/out tiles.
+    8x128x128 blocking (the smallest the TPU's (8, 128) tiling allows) that
+    is 512 KiB live — comfortably inside the ~16 MiB VMEM budget together
+    with the x/w/out tiles.
   * Accumulation is int32.  Callers must respect the documented overflow
     envelope: K * 2^(2*wl - 1 - shift) < 2^31 (asserted in ops.py).
 
@@ -422,8 +423,8 @@ def bbm_matmul_kernel(x_ref, wm_ref, ws_ref, o_ref, *, wl: int, vbl: int,
                                              "bm", "bk", "bn", "interpret",
                                              "form"))
 def bbm_matmul_precoded(x, wmag, wneg, *, wl: int, vbl: int, kind: int = 0,
-                        shift: int = 0, bm: int = 64, bk: int = 64,
-                        bn: int = 64, interpret: bool = False,
+                        shift: int = 0, bm: int = 8, bk: int = 128,
+                        bn: int = 128, interpret: bool = False,
                         form: str | None = None):
     """Tiled approximate matmul on precoded weight-digit planes.
 
@@ -432,7 +433,9 @@ def bbm_matmul_precoded(x, wmag, wneg, *, wl: int, vbl: int, kind: int = 0,
     form: "rows" (VPU row emulation), "dot" (dense contraction + scaled
     truncated rows, on the matmul units) or None (auto: the dot form).
     Bit-identical; ``bm``/``bk``/``bn``/``interpret`` only shape the rows
-    form.
+    form.  Blocks are clamped to the array dims; the TPU lowering needs
+    each block's last two dims divisible by (8, 128) or equal to the
+    array's, which the defaults are.
     """
     mm, kk = x.shape
     n_rows, kk2, nn = wmag.shape
@@ -450,6 +453,7 @@ def bbm_matmul_precoded(x, wmag, wneg, *, wl: int, vbl: int, kind: int = 0,
     if resolve_form(form) == "dot":
         return _matmul_dotform(x, wmag, wneg, wl=wl, vbl=vbl, kind=kind,
                                shift=shift)
+    bm, bk, bn = min(bm, mm), min(bk, kk), min(bn, nn)
     grid = (pl.cdiv(mm, bm), pl.cdiv(nn, bn), pl.cdiv(kk, bk))
     kernel = functools.partial(bbm_matmul_kernel, wl=wl, vbl=vbl, kind=kind,
                                shift=shift, n_k=grid[2])
@@ -464,7 +468,7 @@ def bbm_matmul_precoded(x, wmag, wneg, *, wl: int, vbl: int, kind: int = 0,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), jnp.int32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wmag, wneg)
@@ -474,7 +478,7 @@ def bbm_matmul_precoded(x, wmag, wneg, *, wl: int, vbl: int, kind: int = 0,
                                              "bm", "bk", "bn", "interpret",
                                              "form"))
 def bbm_matmul(x, w, *, wl: int, vbl: int, kind: int = 0, shift: int = 0,
-               bm: int = 64, bk: int = 64, bn: int = 64,
+               bm: int = 8, bk: int = 128, bn: int = 128,
                interpret: bool = False, form: str | None = None):
     """Tiled bit-exact approximate matmul.  x: (M, K) w: (K, N), int32 codes.
 

@@ -116,15 +116,15 @@ def _fir_bank_kernel(x_ref, hm_ref, hs_ref, o_ref, halo_ref, *, wl: int,
     # halo exchange: taps-1 raw codes deposited by the previous time block
     xs = jnp.concatenate([halo_ref[...], x_ref[...]], axis=1)
     _, xs_s = split_signed(xs, wl)          # sign-extend once per block
-    hm = hm_ref[...]                        # (wl//2, bc, taps) digit planes
-    hs = hs_ref[...]
 
     acc = jnp.zeros(o_ref.shape, jnp.int32)
     for k in range(taps):
         # window of samples feeding tap k for each output in the block
         a_s = xs_s[:, taps - 1 - k:taps - 1 - k + bt]
+        # tap k's (wl//2, bc, 1) digit planes as static ref slices: the
+        # TPU lowering has no 3-D gather for indexing a loaded value
         prod = bbm_rows_product_precoded(
-            a_s, hm[:, :, k, None], hs[:, :, k, None],
+            a_s, hm_ref[:, :, k:k + 1], hs_ref[:, :, k:k + 1],
             wl=wl, vbl=vbl, kind=kind)
         if shift:
             prod = prod >> shift
@@ -259,7 +259,7 @@ def fir_bbm_bank_precoded(x, hmag, hneg, *, wl: int, vbl: int, kind: int = 0,
         out_specs=pl.BlockSpec((bc, bt), lambda c, t: (c, t)),
         out_shape=jax.ShapeDtypeStruct((nc * bc, nt * bt), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bc, taps - 1), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, hmp, hsp)

@@ -51,7 +51,7 @@ from .bbm_matmul import dot_scaled_chunked
 from .booth_rows import booth_precode
 from .ref import amm_quantize
 
-__all__ = ["flash_attention", "flash_attention_amm",
+__all__ = ["flash_attention", "flash_attention_amm", "flash_amm_operands",
            "FLASH_AMM_BQ", "FLASH_AMM_BK"]
 
 NEG_INF = -1e30
@@ -210,8 +210,13 @@ def _attn_amm_kernel(qf_ref, kf_ref, vf_ref, qc_ref, km_ref, kn_ref, vc_ref,
                      qs_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      wl: int, vbl: int, kind: int, causal: bool, bq: int,
                      bk: int, n_kv: int, kv_len: int):
-    """Pallas body: ``_amm_tile_step`` + the exact kernel's scratch scheme."""
-    kv_idx = pl.program_id(2)
+    """Pallas body: ``_amm_tile_step`` + the exact kernel's scratch scheme.
+
+    qs/ks/vs are the whole flattened ``(bh * nq|nk,)`` per-block scale
+    arrays in SMEM, read as scalars at this grid point's block.
+    """
+    g, q_idx, kv_idx = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_q = pl.num_programs(1)
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -222,8 +227,9 @@ def _attn_amm_kernel(qf_ref, kf_ref, vf_ref, qc_ref, km_ref, kn_ref, vc_ref,
     m, l, acc = _amm_tile_step(
         m_scr[...], l_scr[...], acc_scr[...],
         qf_ref[0], kf_ref[0], vf_ref[0], qc_ref[0], km_ref[0], kn_ref[0],
-        vc_ref[0], qs_ref[0, 0], ks_ref[0, 0], vs_ref[0, 0],
-        pl.program_id(1), kv_idx, wl=wl, vbl=vbl, kind=kind, causal=causal,
+        vc_ref[0], qs_ref[g * n_q + q_idx], ks_ref[g * n_kv + kv_idx],
+        vs_ref[g * n_kv + kv_idx],
+        q_idx, kv_idx, wl=wl, vbl=vbl, kind=kind, causal=causal,
         bq=bq, bk=bk, kv_len=kv_len)
     m_scr[...] = m
     l_scr[...] = l
@@ -253,6 +259,9 @@ def _flash_amm_pallas(qf, kf, vf, qc, kmag, kneg, vc, qs, ks, vs, *,
                                causal=causal, bq=bq, bk=bk, n_kv=nk,
                                kv_len=kv_len)
     plane_spec = pl.BlockSpec((1, nr, d, bk), lambda g, i, j: (g, 0, 0, j))
+    # per-block scales: a (1, 1) VMEM block is not (8, 128)-tileable, so
+    # each whole scale array sits in SMEM and the body reads its scalar
+    scale_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -264,9 +273,7 @@ def _flash_amm_pallas(qf, kf, vf, qc, kmag, kneg, vc, qs, ks, vs, *,
             plane_spec,                                            # kmag
             plane_spec,                                            # kneg
             pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, 0)),   # vc
-            pl.BlockSpec((1, 1), lambda g, i, j: (g, i)),          # qs
-            pl.BlockSpec((1, 1), lambda g, i, j: (g, j)),          # ks
-            pl.BlockSpec((1, 1), lambda g, i, j: (g, j)),          # vs
+            scale_spec, scale_spec, scale_spec,                    # qs ks vs
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sqp, d), jnp.float32),
@@ -275,10 +282,11 @@ def _flash_amm_pallas(qf, kf, vf, qc, kmag, kneg, vc, qs, ks, vs, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, qc, kmag, kneg, vc, qs, ks, vs)
+    )(qf, kf, vf, qc, kmag, kneg, vc, qs.reshape(-1), ks.reshape(-1),
+      vs.reshape(-1))
 
 
 @functools.partial(jax.jit, static_argnames=("wl", "vbl", "kind", "causal",
@@ -351,6 +359,32 @@ def flash_attention_amm(q, k, v, *, wl: int, vbl: int, kind: int,
     the chunked path holds.
     """
     b, h, sq, d = q.shape
+    ops, bq, bk = flash_amm_operands(q, k, v, wl=wl, bq=bq, bk=bk)
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    if use_kernel:
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        out = _flash_amm_pallas(*ops, wl=wl, vbl=vbl, kind=kind,
+                                causal=causal, bq=bq, bk=bk,
+                                kv_len=k.shape[2], interpret=interpret)
+    else:
+        out = _flash_amm_xla(*ops, wl=wl, vbl=vbl, kind=kind, causal=causal,
+                             bq=bq, bk=bk, kv_len=k.shape[2])
+    return out[:, :sq].reshape(b, h, sq, d).astype(q.dtype)
+
+
+def flash_amm_operands(q, k, v, *, wl: int, bq: int = FLASH_AMM_BQ,
+                       bk: int = FLASH_AMM_BK):
+    """Decode phase of ``flash_attention_amm``: the grid's operands.
+
+    Pads Q/K/V to whole tiles, quantizes them per (batch*head, block)
+    with ``ref.amm_quantize`` and precodes K's digit planes.  Returns
+    ``(ops, bq, bk)``: ``ops = (qf, kf, vf, qc, kmag, kneg, vc, qs, ks,
+    vs)`` as both lowerings take them, the tiles clamped to the sequence
+    lengths.
+    """
+    b, h, sq, d = q.shape
     _, _, skv, _ = k.shape
     bq = min(bq, sq)
     bk = min(bk, skv)
@@ -379,20 +413,4 @@ def flash_attention_amm(q, k, v, *, wl: int, vbl: int, kind: int,
     kmag, kneg = booth_precode(kc.transpose(0, 1, 3, 2), wl)
     kmag = kmag.transpose(1, 0, 3, 2, 4)
     kneg = kneg.transpose(1, 0, 3, 2, 4)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if use_kernel:
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        nr = kmag.shape[1]
-        out = _flash_amm_pallas(
-            qf, kf, vf, qc,
-            kmag.reshape(bh, nr, d, nk * bk),
-            kneg.reshape(bh, nr, d, nk * bk),
-            vc, qs, ks, vs, wl=wl, vbl=vbl, kind=kind, causal=causal,
-            bq=bq, bk=bk, kv_len=skv, interpret=interpret)
-    else:
-        out = _flash_amm_xla(
-            qf, kf, vf, qc, kmag, kneg, vc, qs, ks, vs, wl=wl, vbl=vbl,
-            kind=kind, causal=causal, bq=bq, bk=bk, kv_len=skv)
-    return out[:, :sq].reshape(b, h, sq, d).astype(q.dtype)
+    return (qf, kf, vf, qc, kmag, kneg, vc, qs, ks, vs), bq, bk

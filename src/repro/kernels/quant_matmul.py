@@ -41,7 +41,7 @@ __all__ = ["quant_matmul_kernel", "quant_matmul"]
 def _hash_normal(shape, seed, salt):
     """Two rounds of a squares-style counter hash -> approx N(0,1).
 
-    Box-Muller over two uint32 uniforms derived from (seed, salt, position).
+    Box-Muller over two 31-bit uniforms derived from (seed, salt, position).
     Statistical quality is ample for noise injection (validated in
     tests/test_kernels.py against moment targets).
     """
@@ -59,9 +59,15 @@ def _hash_normal(shape, seed, salt):
         x = x * x + key
         return x
 
-    u1 = squares(ctr, jnp.uint32(0xB5AD4ECE)).astype(jnp.float32) / 4294967296.0
-    u2 = squares(ctr ^ jnp.uint32(0xDEADBEEF),
-                 jnp.uint32(0x548C9DEC)).astype(jnp.float32) / 4294967296.0
+    def uniform(x):
+        # top 31 bits through int32: the TPU lowering has no uint32 -> f32
+        # cast, and a value below 2^31 converts the same either way
+        x = jax.lax.bitcast_convert_type(x >> 1, jnp.int32)
+        return x.astype(jnp.float32) / 2147483648.0
+
+    u1 = uniform(squares(ctr, jnp.uint32(0xB5AD4ECE)))
+    u2 = uniform(squares(ctr ^ jnp.uint32(0xDEADBEEF),
+                         jnp.uint32(0x548C9DEC)))
     u1 = jnp.clip(u1, 1e-7, 1.0)              # uniforms in [0, 1)
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(2.0 * jnp.pi * u2)
 
@@ -85,6 +91,14 @@ def quant_matmul_kernel(x_ref, w_ref, s_ref, seed_ref, o_ref, *, mu: float,
     sw = s_ref[0, 1]
     xq = jnp.clip(jnp.round(x_ref[...] / sx), -lim, lim - 1)
     wq = jnp.clip(jnp.round(w_ref[...] / sw), -lim, lim - 1)
+    bk = x_ref.shape[1]
+    if k_total % bk:
+        # the last K block runs past the operands: its padding is not
+        # zeros (NaN in interpret mode), so zero both sides of the tail
+        col = k_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        row = k_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        xq = jnp.where(col < k_total, xq, 0.0)
+        wq = jnp.where(row < k_total, wq, 0.0)
     acc = jnp.dot(xq, wq, preferred_element_type=jnp.float32)
     o_ref[...] += acc
 

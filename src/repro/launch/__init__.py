@@ -1,8 +1,29 @@
 """Subsystem package: CLI entry points + shared argparse plumbing."""
 from __future__ import annotations
 
-__all__ = ["add_amm_attn_arg", "resolve_amm_apply_to",
+import os
+from pathlib import Path
+
+__all__ = ["add_amm_attn_arg", "resolve_amm_apply_to", "use_compile_cache",
            "validate_amm_args", "validate_serve_flags"]
+
+# fixed, in the checkout: the cache directory is part of every entry's key
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets no path of its own.  Otherwise the cache goes to the fixed
+    ``.jax_cache`` directory at the root of the checkout.
+    """
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def validate_amm_args(ap, args) -> None:

@@ -31,7 +31,7 @@ from ..configs import ARCH_NAMES, SHAPES, get_arch
 from ..configs.base import ArchConfig, ShapeConfig
 from ..models import ModelRuntime, init_cache, lm_logical_axes, lm_table
 from ..models.common import Spec
-from .mesh import HW, make_production_mesh
+from .mesh import make_production_mesh
 
 DEFAULT_OUT = "benchmarks/dryrun_results.json"
 

@@ -4,12 +4,13 @@
         --requests 6 --max-new 16 --amm bitexact --vbl 13
 
 --amm bitexact serves through the true Broken-Booth datapath (dot-form
-lowering); the Scheduler precodes every approximated weight's digit planes
-once at construction, so the per-step cost is the contraction, not the
-decode.  --amm-attn widens the routing to the attention score/value
-products (``--amm-attn`` alone = apply_to="all", ``--amm-attn attn`` =
-attention only); those are activation x activation, so they quantize per
-step — there are no weight planes to cache for them.
+lowering); every approximated weight's digit planes are precoded once into
+the jitted serve fns when they fit the device (``plane_cache_for``), so the
+per-step cost is the contraction, not the decode.  --amm-attn widens the
+routing to the attention score/value products (``--amm-attn`` alone =
+apply_to="all", ``--amm-attn attn`` = attention only); those are
+activation x activation, so they quantize per step — there are no weight
+planes to cache for them.
 
 --continuous switches the Scheduler to continuous batching: requests are
 admitted into free slots every step (prefill on a batch-1 slot slice) and
@@ -31,9 +32,42 @@ from ..configs import ARCH_NAMES, get_arch, reduced
 from ..configs.base import AmmConfig
 from ..models import ModelRuntime, lm_init
 from ..serve.engine import Request, Scheduler, make_serve_fns
-from . import (add_amm_attn_arg, resolve_amm_apply_to,
+from . import (add_amm_attn_arg, resolve_amm_apply_to, use_compile_cache,
                validate_amm_args, validate_serve_flags)
 from .mesh import make_host_mesh
+
+
+def _tree_bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def plane_cache_for(cfg, rt: ModelRuntime, params, bytes_limit=None):
+    """The digit-plane cache to bake into the serve fns, or None.
+
+    With the cache, the bitexact datapath's weight decode happens once and
+    every token after pays contractions only; without it each call decodes
+    inline, bit-identically.  The cache's bytes come from the param shapes
+    (``jax.eval_shape``) before anything is built: at wl=16 it is 64 B per
+    MLP weight, about 20 GB at qwen2-0.5b's published widths.  It is built
+    only when it and the params fit in half of ``bytes_limit`` (default:
+    the first device's ``bytes_limit``; no limit where the backend reports
+    none), leaving the rest for the KV cache and temporaries.  Prints
+    which path was taken.
+    """
+    shapes = jax.eval_shape(lambda p: rt.build_planes(cfg, p), params)
+    if shapes is None:
+        return None
+    need = _tree_bytes(shapes)
+    if bytes_limit is None:
+        stats = jax.devices()[0].memory_stats() or {}
+        bytes_limit = stats.get("bytes_limit")
+    if bytes_limit is not None and \
+            need + _tree_bytes(params) > bytes_limit // 2:
+        print(f"[serve] plane cache {need} B does not fit {bytes_limit} B: "
+              f"serving uncached (weights decoded inline per call)")
+        return None
+    print(f"[serve] plane cache {need} B: serving cached")
+    return rt.build_planes(cfg, params)
 
 
 def main(argv=None):
@@ -69,6 +103,7 @@ def main(argv=None):
     apply_to = resolve_amm_apply_to(ap, args)
     validate_amm_args(ap, args)
     validate_serve_flags(ap, args)
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -79,11 +114,8 @@ def main(argv=None):
                            apply_to=apply_to))
     rt = ModelRuntime.build(cfg, use_pallas=args.flash_attn)
     params = lm_init(cfg, jax.random.key(0))
-    # jitted decode step with the digit-plane cache baked into the closure:
-    # the bitexact datapath's weight decode happens once here, every token
-    # after pays contractions only
     mesh = make_host_mesh(1, 1)
-    planes = rt.build_planes(cfg, params)
+    planes = plane_cache_for(cfg, rt, params)
     prefill_j, decode_j = make_serve_fns(cfg, rt, mesh, batch=args.slots,
                                          max_len=args.max_len,
                                          amm_planes=planes,

@@ -23,7 +23,7 @@ from ..parallel.logical import tree_shardings
 from ..train.loop import LoopConfig, train_loop
 from ..train.optimizer import OptConfig
 from ..train.trainstep import TrainConfig, make_train_step, init_train_state
-from . import (add_amm_attn_arg, resolve_amm_apply_to,
+from . import (add_amm_attn_arg, resolve_amm_apply_to, use_compile_cache,
                validate_amm_args)
 from .mesh import make_host_mesh
 
@@ -60,6 +60,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     apply_to = resolve_amm_apply_to(ap, args)
     validate_amm_args(ap, args)
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -69,8 +69,6 @@ def compressed_allreduce(grads, mesh: Mesh, codec: str = "int8",
     shard_map treats each leaf as locally owned and psums the quantized
     payload.  Returns (mean_grads, new_error_buf).
     """
-    from jax.experimental.shard_map import shard_map
-
     if error_buf is None:
         error_buf = jax.tree.map(jnp.zeros_like, grads)
 
@@ -105,10 +103,10 @@ def compressed_allreduce(grads, mesh: Mesh, codec: str = "int8",
         return (tdef.unflatten([m for m, _ in res]),
                 tdef.unflatten([e2 for _, e2 in res]))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis), P(axis)),      # leading dim owned per data shard
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(grads, error_buf)

@@ -75,8 +75,6 @@ def sharded_filterbank(x, h, mesh: Mesh, *, wl: int, vbl: int, kind: int = 0,
     decoded once, not once per call; when omitted the decode still runs
     only once per call, outside the shard_map.
     """
-    from jax.experimental.shard_map import shard_map
-
     if h.ndim == 1:
         h = jnp.broadcast_to(h[None, :], (x.shape[0], h.shape[0]))
     # the kernel path checks this itself; the closed-form host path would
@@ -110,22 +108,22 @@ def sharded_filterbank(x, h, mesh: Mesh, *, wl: int, vbl: int, kind: int = 0,
         apply_fn = functools.partial(fir_bbm_bank_precoded, wl=wl, vbl=vbl,
                                      kind=kind, shift=shift, bc=bc, bt=bt,
                                      interpret=not on_tpu(), form=form)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda xs, hm, hn: apply_fn(xs, hm, hn),
             mesh=mesh,
             in_specs=(P(axis, None), P(None, axis, None),
                       P(None, axis, None)),
             out_specs=P(axis, None),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(x, hmag, hneg)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xs, hs: fir_bank_ref(xs, hs, wl=wl, vbl=vbl, kind=kind,
                                     shift=shift),
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None)),
         out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, h)

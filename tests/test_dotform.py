@@ -37,6 +37,7 @@ from repro.kernels import (bbm_matmul_precoded, bbm_rows_product_dotform,
                            resolve_form)
 from repro.kernels.booth_rows import num_corr_rows, split_signed
 from repro.kernels.ref import bbm_matmul_ref, fir_bank_ref
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(23)
 
@@ -333,7 +334,7 @@ def test_engine_and_sharded_pick_dot_automatically():
 
     # sharded: use_kernel=None resolves to the kernel+dot path off-TPU
     wl, vbl, kind, shift = 16, 13, 1, 5
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jnp.asarray(RNG.integers(0, 1 << wl, (4, 256)), jnp.int32)
     h = jnp.asarray(RNG.integers(0, 1 << wl, (4, 31)), jnp.int32)
     ref = fir_bank_ref(x, h, wl=wl, vbl=vbl, kind=kind, shift=shift)

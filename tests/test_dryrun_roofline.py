@@ -146,3 +146,16 @@ def test_dryrun_results_if_present():
             if (a, s, "16x16") not in seen:
                 missing.append(f"{a}/{s}")
     assert not missing, f"missing single-pod cells: {missing}"
+
+
+def test_peaks_keyed_by_device_kind():
+    """Peaks come from one table keyed by ``device_kind`` (Google Cloud,
+    "TPU v5e"); a kind with no published entry raises, never defaults."""
+    from repro.launch.mesh import PRODUCTION_KIND, peaks
+    v5e = peaks("TPU v5 lite")
+    assert v5e["peak_flops_bf16"] == 197e12
+    assert v5e["peak_ops_int8"] == 393e12
+    assert v5e["hbm_bw"] == 819e9
+    assert peaks(PRODUCTION_KIND) is v5e
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")

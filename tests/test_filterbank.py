@@ -16,6 +16,7 @@ from repro.core.multipliers import MulSpec
 from repro.dsp import design_lowpass, fir_apply, fir_apply_fixed
 from repro.kernels import bbm_matmul, fir_bbm, fir_bbm_bank, min_safe_shift
 from repro.kernels.ref import fir_bank_ref
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(7)
 
@@ -159,7 +160,7 @@ def test_min_safe_shift_is_minimal():
 def test_sharded_filterbank_single_device_mesh():
     from repro.parallel import sharded_filterbank
     wl, vbl, kind = 12, 9, 0
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x, h = _bank_case(4, 256, 31, wl)
     got = sharded_filterbank(x, h, mesh, wl=wl, vbl=vbl, kind=kind)
     ref = fir_bank_ref(x, h, wl=wl, vbl=vbl, kind=kind)

@@ -54,9 +54,11 @@ def test_bbm_matmul_exactness_at_vbl0():
 
 
 # ------------------------------------------------------------ quant_matmul
-@pytest.mark.parametrize("shape", [(32, 128, 32), (64, 256, 48), (16, 64, 16)])
+@pytest.mark.parametrize("shape", [(32, 128, 32), (64, 256, 48), (16, 64, 16),
+                                   (32, 96, 32)])
 def test_quant_matmul_noiseless_exact(shape):
-    """With sums inside f32's exact-int range the kernel == oracle bitwise."""
+    """With sums inside f32's exact-int range the kernel == oracle bitwise,
+    K tail included (96 is not a multiple of the 64-wide K block)."""
     m, k, n = shape
     x = jnp.asarray(RNG.standard_normal((m, k)), jnp.float32)
     w = jnp.asarray(RNG.standard_normal((k, n)), jnp.float32)

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_host_mesh
 from repro.parallel.logical import (OPT_RULES_MULTIPOD, RULES,
                                     RULES_MULTIPOD, batch_pspec,
                                     spec_to_pspec)
@@ -40,7 +41,7 @@ def test_rules_multipod_batch():
 
 
 def test_divisibility_dropping():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
 
     class FakeMesh:
         shape = {"data": 16, "model": 16, "pod": 2}
@@ -65,7 +66,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, json
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh, make_mesh
+
+mesh = make_host_mesh(4, 2)
 
 # --- compressed allreduce over the data axis
 from repro.parallel.compress import compressed_allreduce, allreduce_ref
@@ -82,7 +85,7 @@ assert err < 0.05, err
 # --- channel-sharded filterbank: 8 channels over 8 data shards
 from repro.parallel.filterbank import sharded_filterbank
 from repro.kernels.ref import fir_bank_ref
-mesh1 = jax.make_mesh((8,), ("data",))
+mesh1 = make_mesh((8,), ("data",))
 xc = jnp.asarray(rng.integers(0, 1 << 12, (8, 256)), jnp.int32)
 hc = jnp.asarray(rng.integers(0, 1 << 12, (8, 31)), jnp.int32)
 got_fb = sharded_filterbank(xc, hc, mesh1, wl=12, vbl=9, kind=1)

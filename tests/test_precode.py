@@ -23,6 +23,7 @@ from repro.kernels import (bbm_matmul, bbm_matmul_precoded, booth_precode,
 from repro.kernels.booth_rows import (bbm_rows_product,
                                       bbm_rows_product_precoded,
                                       split_signed)
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(11)
 
@@ -155,7 +156,7 @@ def test_sharded_filterbank_precoded_planes_path():
     from repro.parallel import precode_filterbank, sharded_filterbank
     from repro.kernels.ref import fir_bank_ref
     wl, vbl, kind = 12, 9, 1
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jnp.asarray(RNG.integers(0, 1 << wl, (4, 256)), jnp.int32)
     h = jnp.asarray(RNG.integers(0, 1 << wl, (4, 31)), jnp.int32)
     ref = fir_bank_ref(x, h, wl=wl, vbl=vbl, kind=kind)

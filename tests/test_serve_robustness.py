@@ -317,3 +317,35 @@ def test_serve_launcher_rejects_kv_codes_without_booth_attention():
     for argv in bad:
         with pytest.raises(SystemExit):
             serve_main(["--reduced"] + argv)
+
+
+def test_serve_plane_cache_sized_before_it_is_built(capsys):
+    """``plane_cache_for`` works out the digit-plane cache's bytes from the
+    param shapes (4*wl B per MLP weight plus one f32 scale per weight
+    matrix) and builds it only when cache and params fit in half the
+    device's bytes; otherwise it serves uncached, which is bit-identical."""
+    import jax.numpy as jnp
+    from repro.launch.serve import plane_cache_for
+    from repro.models import lm_apply
+    wl = 16
+    cfg = dataclasses.replace(reduced(get_arch("qwen2-0.5b")), amm=AmmConfig(
+        mode="bitexact", mul="bbm0", wl=wl, param=13, apply_to="mlp"))
+    rt = ModelRuntime.build(cfg)
+    params = lm_init(cfg, jax.random.key(0))
+    weights = 3 * cfg.n_layers * cfg.d_model * cfg.d_ff
+    need = 4 * wl * weights + 4 * 3 * cfg.n_layers
+    total = need + sum(x.nbytes for x in jax.tree.leaves(params))
+
+    planes = plane_cache_for(cfg, rt, params, bytes_limit=2 * total)
+    assert planes is not None
+    assert f"plane cache {need} B: serving cached" in capsys.readouterr().out
+    assert plane_cache_for(cfg, rt, params, bytes_limit=2 * total - 2) is None
+    assert "serving uncached" in capsys.readouterr().out
+
+    toks = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
+    cached, _, _ = lm_apply(params, cfg, rt, toks, amm_planes=planes)
+    uncached, _, _ = lm_apply(params, cfg, rt, toks)
+    assert_array_equal(np.asarray(cached), np.asarray(uncached))
+
+    off = dataclasses.replace(cfg, amm=AmmConfig(mode="off"))
+    assert plane_cache_for(off, ModelRuntime.build(off), params) is None
