@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from scipy.signal import remez
 
+from .. import trace
 from ..core.multipliers import MulSpec, mul
 from ..kernels.booth_rows import booth_precode, resolve_form
 from ..kernels.fir_kernel import (_DOT_WINDOW_BUDGET, fir_bbm_bank_precoded,
@@ -345,8 +346,9 @@ def fir_apply(x: np.ndarray, h, spec: MulSpec | None = None, *,
         # so neither needs (or should pay for) a default shift
         shift = 0 if (datapath == "wlbit" or wl > 16) \
             else min_safe_shift(taps, wl)
-    amp = _amp(x2)
-    xq = _quantize64(x2 * amp, wl)
+    with trace.span("fir.quantize"):
+        amp = _amp(x2)
+        xq = _quantize64(x2 * amp, wl)
     if bank is None:
         # one-shot bank: defer the decode to the first ``planes`` read —
         # the Booth-family dot path (either backend) triggers it once per
@@ -382,11 +384,19 @@ def _apply_pallas(xq, bank: PrecodedBank, *, datapath, shift, amp, bc,
     # fused code-level pipeline: one transfer in, one jitted dispatch on the
     # cached digit planes (sign-extend + accumulate form), one out
     hmag, hneg = bank.planes
-    out = fir_filterbank_precoded(jnp.asarray(_codes32(xq, wl)), hmag, hneg,
-                                  wl=wl, vbl=vbl, kind=BBM_KINDS[spec.name],
-                                  shift=shift, interpret=interpret, bc=bc,
-                                  bt=block, form=form)
-    return _descale(np.asarray(out, np.float64), wl, shift, amp)
+    with trace.span("fir.quantize"):
+        codes = _codes32(xq, wl)
+    with trace.span("fir.to_device"):
+        codes = jnp.asarray(codes)
+    with trace.span("fir.dispatch"):
+        out = fir_filterbank_precoded(codes, hmag, hneg, wl=wl, vbl=vbl,
+                                      kind=BBM_KINDS[spec.name], shift=shift,
+                                      interpret=interpret, bc=bc, bt=block,
+                                      form=form)
+    with trace.span("fir.fetch"):
+        acc = np.asarray(out, np.float64)
+    with trace.span("fir.descale"):
+        return _descale(acc, wl, shift, amp)
 
 
 def _apply_host(xq, bank: PrecodedBank, *, datapath, shift, amp, form=None):

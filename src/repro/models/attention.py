@@ -49,6 +49,7 @@ from typing import Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import trace
 from ..configs.base import ArchConfig
 from .common import Spec, amm_dot, apply_rope, rmsnorm
 
@@ -567,23 +568,24 @@ def attention(p, x, cfg: ArchConfig, *, positions, cache=None, pos=None,
     """
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    if kv is None:
-        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    else:
-        k, v = kv
-    if cfg.qkv_bias:
-        q = q + p["bq"]
+    with jax.named_scope(trace.ATTN_PROJ):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
         if kv is None:
-            k = k + p["bk"]
-            v = v + p["bv"]
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    if kv is None:
-        k = apply_rope(k, positions, cfg.rope_theta)
+            k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+        else:
+            k, v = kv
+        if cfg.qkv_bias:
+            q = q + p["bq"]
+            if kv is None:
+                k = k + p["bk"]
+                v = v + p["bv"]
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        if kv is None:
+            k = apply_rope(k, positions, cfg.rope_theta)
 
     new_cache = None
     if cache is not None and s > 1 and jnp.ndim(pos) == 1:
@@ -597,21 +599,22 @@ def attention(p, x, cfg: ArchConfig, *, positions, cache=None, pos=None,
             raise ValueError("int-code KV cache requires an active "
                              "Booth-family bitexact amm attention lowering")
         wl = amm.attn_lowering[0]
-        ck, sk = code_cache_update(cache["k_codes"], cache["k_scale"], k,
-                                   pos, wl=wl)
-        cv, sv = code_cache_update(cache["v_codes"], cache["v_scale"], v,
-                                   pos, wl=wl)
-        new_cache = {"k_codes": ck, "k_scale": sk,
-                     "v_codes": cv, "v_scale": sv}
-        if s == 1:
-            out = decode_attention_codes(q, new_cache, kv_len=pos + s,
-                                         amm=amm)
-        else:
-            kk = code_cache_dequant(ck, sk, kv_len=pos + s)
-            vv = code_cache_dequant(cv, sv, kv_len=pos + s)
-            out = chunked_attention(q, kk, vv, causal=causal, q_offset=pos,
-                                    kv_len=pos + s,
-                                    remat_qblock=remat_qblock, amm=amm)
+        with jax.named_scope(trace.ATTN_CODE_CACHE):
+            ck, sk = code_cache_update(cache["k_codes"], cache["k_scale"],
+                                       k, pos, wl=wl)
+            cv, sv = code_cache_update(cache["v_codes"], cache["v_scale"],
+                                       v, pos, wl=wl)
+            new_cache = {"k_codes": ck, "k_scale": sk,
+                         "v_codes": cv, "v_scale": sv}
+            if s == 1:
+                out = decode_attention_codes(q, new_cache, kv_len=pos + s,
+                                             amm=amm)
+            else:
+                kk = code_cache_dequant(ck, sk, kv_len=pos + s)
+                vv = code_cache_dequant(cv, sv, kv_len=pos + s)
+                out = chunked_attention(q, kk, vv, causal=causal,
+                                        q_offset=pos, kv_len=pos + s,
+                                        remat_qblock=remat_qblock, amm=amm)
     elif cache is not None:
         ck = _cache_put(cache["k"], k.astype(cache["k"].dtype), pos)
         cv = _cache_put(cache["v"], v.astype(cache["v"].dtype), pos)
@@ -676,7 +679,8 @@ def attention(p, x, cfg: ArchConfig, *, positions, cache=None, pos=None,
                                 remat_qblock=remat_qblock,
                                 causal_skip=causal_skip, p_bf16=p_bf16,
                                 amm=amm)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    with jax.named_scope(trace.ATTN_PROJ):
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 
 

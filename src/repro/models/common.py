@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from ..configs.base import AmmConfig
 from ..core.multipliers import MulSpec
 from ..core.noise import make_noise_model
@@ -205,14 +206,16 @@ def _amm_bitexact_approx(x, w, rt: AmmRuntime, planes=None):
         return amm_approx_ref(x, w, rt.spec)
     wl = cfg.wl
     vbl = amm_effective_vbl(rt.spec)
-    xq, s_x = amm_quantize(x, wl)
+    with jax.named_scope(trace.AMM_CONTRACT):
+        xq, s_x = amm_quantize(x, wl)
     if planes is None:
-        planes = rt.precode(w)
-    s_w = planes["s_w"]
-    yq = bbm_matmul_scaled(xq.reshape(-1, x.shape[-1]), planes["mag"],
-                           planes["neg"], wl=wl, vbl=vbl, kind=kind)
-    yq = yq.reshape(x.shape[:-1] + (w.shape[-1],))
-    return (yq * (s_x * s_w)).astype(x.dtype)
+        with jax.named_scope(trace.AMM_WEIGHT_DECODE):
+            planes = rt.precode(w)
+    with jax.named_scope(trace.AMM_CONTRACT):
+        yq = bbm_matmul_scaled(xq.reshape(-1, x.shape[-1]), planes["mag"],
+                               planes["neg"], wl=wl, vbl=vbl, kind=kind)
+        yq = yq.reshape(x.shape[:-1] + (w.shape[-1],))
+        return (yq * (s_x * planes["s_w"])).astype(x.dtype)
 
 
 def amm_dense(x, w, rt: AmmRuntime, key=None, planes=None):
@@ -227,7 +230,8 @@ def amm_dense(x, w, rt: AmmRuntime, key=None, planes=None):
     the hot loop; bit-identical with or without.
     """
     cfg = rt.cfg
-    exact = x @ w
+    with jax.named_scope(trace.AMM_STE_EXACT):
+        exact = x @ w
     if cfg.mode == "off":
         return exact
     if cfg.mode == "noise":
@@ -267,7 +271,8 @@ def amm_dense(x, w, rt: AmmRuntime, key=None, planes=None):
         return exact + jax.lax.stop_gradient(approx - exact)
     if cfg.mode == "bitexact":
         approx = _amm_bitexact_approx(x, w, rt, planes=planes)
-        return exact + jax.lax.stop_gradient(approx - exact)
+        with jax.named_scope(trace.AMM_STE_EXACT):
+            return exact + jax.lax.stop_gradient(approx - exact)
     raise ValueError(f"unknown amm mode {cfg.mode!r}")
 
 

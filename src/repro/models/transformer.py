@@ -24,6 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import trace
 from ..configs.base import ArchConfig
 from .attention import attention, attn_table, mla_attention, mla_table
 from .common import (AmmRuntime, Spec, cross_entropy_loss, init_params,
@@ -520,9 +521,11 @@ def lm_apply(params, cfg: ArchConfig, rt: ModelRuntime, tokens, *,
     else:
         raise ValueError(cfg.family)
 
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
+    with jax.named_scope(trace.LM_HEAD):
+        h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
     return logits, {"moe_aux": aux_total}, new_caches
 
 

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import trace
 from ..configs.base import ArchConfig
 from ..core.guards import GuardConfig, finite_rows
 from ..models import ModelRuntime, init_cache, lm_amm_planes, lm_apply
@@ -189,6 +190,10 @@ class FilterbankEngine:
     re-quantize/re-recode), runs the whole batch through ``dsp.fir_apply``
     (host or Pallas backend) in a single call, and returns each request's
     output trimmed back to its own length.
+
+    Building an engine installs ``repro.trace.gc_spans()`` (once per
+    process): while a profiler trace runs, every garbage collection of the
+    process is written to it as a ``repro.host.gc`` span.
     """
 
     def __init__(self, h_banks: np.ndarray, spec, *, backend: str = "host",
@@ -228,6 +233,7 @@ class FilterbankEngine:
         self.stats = {"dispatches": 0, "served": 0, "retries": 0,
                       "bisections": 0, "quarantined": 0, "guard_trips": 0,
                       "exact_reserves": 0}
+        trace.gc_spans()
 
     def submit(self, signal: np.ndarray, bank: int = 0) -> int:
         """Queue one signal; returns its request id."""
@@ -254,26 +260,29 @@ class FilterbankEngine:
         transparently re-served on the exact datapath.
         """
         results: Dict[int, np.ndarray] = {}
-        while self._pending:
-            batch = self._pending[: self.max_channels]
-            # dequeue *before* serving: failures below are retried,
-            # bisected, and at worst quarantined — never left to wedge
-            # the queue for every later flush
-            self._pending = self._pending[self.max_channels:]
-            self._serve(batch, results)
+        with trace.span("fir.flush"):
+            while self._pending:
+                batch = self._pending[: self.max_channels]
+                # dequeue *before* serving: failures below are retried,
+                # bisected, and at worst quarantined — never left to wedge
+                # the queue for every later flush
+                self._pending = self._pending[self.max_channels:]
+                self._serve(batch, results)
         return results
 
     def _stack(self, batch: List[FilterRequest]) -> np.ndarray:
-        n = max(len(r.signal) for r in batch)
-        x = np.zeros((len(batch), n))
-        for c, r in enumerate(batch):
-            x[c, : len(r.signal)] = r.signal
+        with trace.span("fir.stack"):
+            n = max(len(r.signal) for r in batch)
+            x = np.zeros((len(batch), n))
+            for c, r in enumerate(batch):
+                x[c, : len(r.signal)] = r.signal
         return x
 
     def _dispatch(self, batch: List[FilterRequest]) -> np.ndarray:
         """One filterbank call with bounded retry; raises when exhausted."""
         x = self._stack(batch)
-        h = self.bank.take([r.bank for r in batch])
+        with trace.span("fir.bank_take"):
+            h = self.bank.take([r.bank for r in batch])
         for attempt in range(self.max_retries + 1):
             self.stats["dispatches"] += 1
             self._dispatches += 1
@@ -307,12 +316,13 @@ class FilterbankEngine:
             self._serve(batch[mid:], results)
             return
         bad = self._guard_channels(batch, y)
-        for c, r in enumerate(batch):
-            if c in bad:
-                results[r.rid] = self._reserve_exact(r)
-            else:
-                results[r.rid] = y[c, : len(r.signal)]
-            self.stats["served"] += 1
+        with trace.span("fir.split"):
+            for c, r in enumerate(batch):
+                if c in bad:
+                    results[r.rid] = self._reserve_exact(r)
+                else:
+                    results[r.rid] = y[c, : len(r.signal)]
+                self.stats["served"] += 1
 
     def _guard_channels(self, batch: List[FilterRequest],
                         y: np.ndarray) -> set:
@@ -403,6 +413,9 @@ class Scheduler:
     per-call K/V requantize, and a token's quantized representation never
     drifts as later tokens arrive.
 
+    Building a scheduler installs ``repro.trace.gc_spans()`` (once per
+    process), as ``FilterbankEngine`` does.
+
     Degradation policy (all opt-in, all off on the lean default path):
 
       * a raising decode step is retried ``max_retries`` times with capped
@@ -481,6 +494,7 @@ class Scheduler:
         # and hold a second copy of the (wl//2, K, N) planes.
         self.amm_planes = (lm_amm_planes(cfg, rt.amm, params)
                            if decode_fn is None else None)
+        trace.gc_spans()
 
     def submit(self, req: Request):
         """Queue one request; invalid specs raise here, not mid-serve.
@@ -690,30 +704,34 @@ class Scheduler:
         req = self.slots[i]
         toks = list(req.prompt) or [0]
         fn = self.prefill_fn or self._default_prefill
-        sub = slot_take(self.caches, self._bax, i)
+        with trace.span("sched.slot_take"):
+            sub = slot_take(self.caches, self._bax, i)
         last = None
-        for attempt in range(self.max_retries + 1):
-            try:
-                logits, sub = fn(self.params, jnp.asarray([toks], jnp.int32),
-                                 sub)
-                break
-            except Exception as e:
-                last = e
-                if attempt < self.max_retries:
-                    self.stats["retries"] += 1
-                    if self.backoff > 0:
-                        time.sleep(min(self.backoff * (2 ** attempt),
-                                       self.backoff_cap))
-        else:
-            self._fail(i, f"prefill failed: {last!r}")
-            return
-        self.caches = slot_put(self.caches, self._bax, sub, i)
+        with trace.span("sched.prefill"):
+            for attempt in range(self.max_retries + 1):
+                try:
+                    logits, sub = fn(self.params,
+                                     jnp.asarray([toks], jnp.int32), sub)
+                    break
+                except Exception as e:
+                    last = e
+                    if attempt < self.max_retries:
+                        self.stats["retries"] += 1
+                        if self.backoff > 0:
+                            time.sleep(min(self.backoff * (2 ** attempt),
+                                           self.backoff_cap))
+            else:
+                self._fail(i, f"prefill failed: {last!r}")
+                return
+        with trace.span("sched.slot_put"):
+            self.caches = slot_put(self.caches, self._bax, sub, i)
         self.pos[i] = len(toks)
         self.stats["prefills"] += 1
         self.stats["decoded"] += len(toks)
         req._pending = []
-        req.out.append(int(np.asarray(jnp.argmax(logits, axis=-1)
-                                      ).reshape(-1)[0]))
+        with trace.span("sched.first_token"):
+            req.out.append(int(np.asarray(jnp.argmax(logits, axis=-1)
+                                          ).reshape(-1)[0]))
         if len(req.out) >= req.max_new or self.pos[i] >= self.max_len - 1:
             self._finish(i)
 
@@ -740,8 +758,10 @@ class Scheduler:
                 req._steps = 0
                 req._pending = []
                 self.pos[i] = 0
-                self.caches = reset_slot(self.caches, self._bax, i)
-                self._prefill_slot(i)    # may fail or finish the slot
+                with trace.span("sched.admit"):
+                    with trace.span("sched.reset_slot"):
+                        self.caches = reset_slot(self.caches, self._bax, i)
+                    self._prefill_slot(i)    # may fail or finish the slot
                 admitted += 1
         live = [i for i, s in enumerate(self.slots) if s is not None]
         if not live:
@@ -756,7 +776,8 @@ class Scheduler:
                  and self.stats["steps"] % self.guard.budget_every == 0)
         pre_caches = self._snapshot() if audit else None
         n_live = len(live)
-        logits, live = self._decode_isolated(fn, toks, pos, live)
+        with trace.span("sched.decode"):
+            logits, live = self._decode_isolated(fn, toks, pos, live)
         if logits is None:
             return n_live
         for i in self._guard_slots(logits, toks, pos, pre_caches, live):
@@ -764,18 +785,21 @@ class Scheduler:
             self.slots[i] = None
             self.pos[i] = 0
             live = [j for j in live if j != i]
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for i in live:
-            s = self.slots[i]
-            self.pos[i] += 1
-            s._steps += 1
-            self.stats["decoded"] += 1
-            s.out.append(int(nxt[i]))
-            if len(s.out) >= s.max_new or self.pos[i] >= self.max_len - 1:
-                self._finish(i)
-            elif s.deadline is not None and s._steps >= s.deadline:
-                self._fail(i, "deadline")
-                self.stats["deadline_expired"] += 1
+        with trace.span("sched.sample"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with trace.span("sched.commit"):
+            for i in live:
+                s = self.slots[i]
+                self.pos[i] += 1
+                s._steps += 1
+                self.stats["decoded"] += 1
+                s.out.append(int(nxt[i]))
+                if len(s.out) >= s.max_new \
+                        or self.pos[i] >= self.max_len - 1:
+                    self._finish(i)
+                elif s.deadline is not None and s._steps >= s.deadline:
+                    self._fail(i, "deadline")
+                    self.stats["deadline_expired"] += 1
         return n_live
 
     def step(self) -> int:
