@@ -372,29 +372,47 @@ def bbm_matmul_coded_kblocks(a, b_codes, s_b, *, wl: int, vbl: int,
     """``bbm_matmul_coded`` with per-K-block ``b`` scales (the PV product).
 
     The V cache quantizes rows in groups of ``block`` positions, so the
-    contraction cannot descale once at the end: each K-block contracts as
-    codes through ``bbm_matmul_scaled`` and descales by its own
-    ``s_a * s_b[j]`` before the float32 combine, accumulated in block
-    order (float addition order is part of the bitwise contract — with a
-    single block this reduces exactly to ``bbm_matmul_coded``).  ``a``'s
-    dynamic scale is derived once over the whole (M, K) slice, matching
-    what the dynamic entry would compute for the same ``a``.
+    contraction cannot descale once at the end: each K-block's integer
+    partial descales by its own ``s_a * s_b[j]`` before the float32
+    combine.  All ``K // block`` blocks contract at once: the codes are
+    regrouped to (nb, M, block) x (nb, block, N), and one
+    ``booth_precode`` + ``bbm_matmul_scaled`` vmapped over the block axis
+    gives every block's exact integer partial — the same contractions as
+    a per-block loop, as one batched call, so the op count does not grow
+    with the number of blocks.  The digit planes are decoded inside the
+    vmap so they come out block axis first (decoding the whole slice and
+    then moving the block axis in front costs a transpose of every
+    plane).  ``a``'s dynamic scale is derived once over the whole (M, K)
+    slice, matching what the dynamic entry would compute for the same
+    ``a``.
+
+    The descaled blocks are then combined by a chain of adds in block
+    order.  Float addition order is part of the bitwise contract with
+    ``ref.amm_coded_kblocks_ref``: a reduction over the block axis
+    (``jnp.sum``) is free to reassociate the float adds (pairwise or
+    tree order) and would break it.  With a single block this reduces
+    exactly to ``bbm_matmul_coded``.
 
     a: (M, K) float; b_codes: (K, N) codes with K % block == 0;
     s_b: (K // block,) f32.
     """
-    kk = b_codes.shape[0]
+    kk, nn = b_codes.shape
     if kk % block:
         raise ValueError(f"K={kk} not a multiple of block={block}")
+    nb = kk // block
     aq, s_a = amm_quantize(a, wl)
-    b_codes = jnp.asarray(b_codes, jnp.int32)
-    acc = None
-    for bi, lo in enumerate(range(0, kk, block)):
-        mag, neg = booth_precode(b_codes[lo:lo + block], wl)
-        yq = bbm_matmul_scaled(aq[:, lo:lo + block], mag, neg,
-                               wl=wl, vbl=vbl, kind=kind)
-        part = yq * (s_a * s_b[bi])
-        acc = part if acc is None else acc + part
+    aq = aq.reshape(aq.shape[0], nb, block).transpose(1, 0, 2)
+    b_codes = jnp.asarray(b_codes, jnp.int32).reshape(nb, block, nn)
+
+    def block_partial(x, codes):
+        mag, neg = booth_precode(codes, wl)
+        return bbm_matmul_scaled(x, mag, neg, wl=wl, vbl=vbl, kind=kind)
+
+    yq = jax.vmap(block_partial)(aq, b_codes)                # (nb, M, N)
+    parts = yq * (s_a * jnp.asarray(s_b, jnp.float32))[:, None, None]
+    acc = parts[0]
+    for bi in range(1, nb):
+        acc = acc + parts[bi]
     return acc.astype(a.dtype)
 
 

@@ -15,6 +15,7 @@ parity at the LM level, and verifies the flash-amm routing:
 the flash tile sizes (the full contract lives in tests/test_flash_amm.py).
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,10 +24,13 @@ import pytest
 
 from repro.configs import AmmConfig, get_arch, reduced
 from repro.core.multipliers import MulSpec
-from repro.kernels.bbm_matmul import bbm_matmul_dynamic
+from repro.kernels.bbm_matmul import (bbm_matmul_coded_kblocks,
+                                      bbm_matmul_dynamic)
 from repro.kernels.ref import (AMM_BOOTH_KINDS, amm_attention_ref,
+                               amm_coded_kblocks_ref,
                                amm_decode_attention_codes_ref,
-                               amm_decode_attention_ref, amm_dot_ref)
+                               amm_decode_attention_ref, amm_dot_ref,
+                               amm_quantize)
 from repro.models import ModelRuntime, init_cache, lm_apply, lm_init
 from repro.models import attention as attention_mod
 from repro.models.attention import (attention, attn_table, chunked_attention,
@@ -374,6 +378,57 @@ def test_decode_attention_codes_matches_codes_oracle(mul, wl, vbl):
     ref = amm_decode_attention_codes_ref(q, cache, kv_len,
                                          MulSpec(mul, wl, vbl))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def _kblock_operands(wl, nb, *, block=16, m=7, n=64, seed=23):
+    """PV-product operands: (M, K) floats, and V codes quantized per
+    block of ``block`` rows with one envelope-edge row per block (a
+    different magnitude each), so every block's scale differs."""
+    rng = np.random.default_rng(seed)
+    a = rng.standard_normal((m, nb * block))
+    v = rng.standard_normal((nb * block, n))
+    codes, scales = [], []
+    for j in range(nb):
+        blk = v[j * block:(j + 1) * block].copy()
+        blk[j % block] *= 10.0 * (1 + j)
+        c, s = amm_quantize(jnp.asarray(blk, jnp.float32), wl)
+        codes.append(np.asarray(c))
+        scales.append(np.asarray(s))
+    return (jnp.asarray(a, jnp.float32), jnp.asarray(np.concatenate(codes)),
+            jnp.asarray(np.stack(scales), jnp.float32))
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("mul,wl,vbl", [("bbm0", 8, 5), ("bbm1", 8, 7),
+                                        ("bbm0", 16, 13), ("bbm1", 16, 15)])
+def test_coded_kblocks_matches_kblocks_oracle(mul, wl, vbl, jit):
+    """The PV product at the served geometry (48 blocks of 16, M = 7,
+    N = 64) == the per-block scalar oracle, eager and jitted (the served
+    decode is jitted): one batched contraction over every block and the
+    block-order combine give the oracle's bits."""
+    a, codes, scales = _kblock_operands(wl, 48)
+    assert len(set(np.asarray(scales).tolist())) == 48
+    fn = lambda a_, c_, s_: bbm_matmul_coded_kblocks(
+        a_, c_, s_, wl=wl, vbl=vbl, kind=AMM_BOOTH_KINDS[mul], block=16)
+    got = (jax.jit(fn) if jit else fn)(a, codes, scales)
+    ref = amm_coded_kblocks_ref(a, codes, scales, MulSpec(mul, wl, vbl),
+                                block=16)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_coded_kblocks_contracts_all_blocks_at_once():
+    """The jitted PV product holds as many dots at 48 K-blocks as at one
+    (36 at wl = 16, vbl = 13, Type 0: the high-digit dot plus 7 truncated
+    rows x (digit dot + 4 residue dots)) — a per-block loop would hold
+    48 x 36."""
+    def n_dots(nb):
+        a, codes, scales = _kblock_operands(16, nb)
+        fn = jax.jit(lambda a_, c_, s_: bbm_matmul_coded_kblocks(
+            a_, c_, s_, wl=16, vbl=13, kind=0, block=16))
+        hlo = fn.lower(a, codes, scales).compile().as_text()
+        return len(re.findall(r"= \S+ dot\(", hlo))
+
+    assert n_dots(1) == n_dots(48) == 36
 
 
 @pytest.mark.parametrize("mul,wl,vbl", SWEEP)
