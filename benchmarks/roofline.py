@@ -34,7 +34,8 @@ def param_count(arch: str) -> Dict[str, float]:
     emb = v * d * (1 if c.tie_embeddings else 2)
     per_layer_attn = 0.0
     if c.use_mla:
-        per_layer_attn = (d * c.q_lora_rank + c.q_lora_rank * c.n_heads
+        q_in = c.q_lora_rank or d      # no q-LoRA: a direct projection
+        per_layer_attn = (d * c.q_lora_rank + q_in * c.n_heads
                           * (c.qk_nope_dim + c.qk_rope_dim)
                           + d * (c.kv_lora_rank + c.qk_rope_dim)
                           + c.kv_lora_rank * c.n_heads

@@ -12,6 +12,7 @@ _MODULES = {
     "whisper-base": "whisper_base",
     "chameleon-34b": "chameleon_34b",
     "zamba2-2.7b": "zamba2_2_7b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_NAMES = sorted(_MODULES)

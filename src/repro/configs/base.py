@@ -67,9 +67,15 @@ class ArchConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     first_k_dense: int = 0
+    routed_scaling_factor: float = 1.0  # routed-expert weights, after the
+                                        # normalization over the top-k
+    router_bias: bool = False   # DeepSeek-V3 noaux_tc: a correction bias
+                                # added to the scores to choose experts only
+    experts_held: int = 0       # expert share: routed experts this device
+    expert_offset: int = 0      # holds (0: all), from expert_offset on
     # --- MLA (deepseek) ---
     use_mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0        # 0: a direct query projection (no q-LoRA)
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -97,6 +103,11 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def n_local_experts(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def attn_free(self) -> bool:

@@ -164,7 +164,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         with mesh_ctx:
             if shape.kind == "prefill":
                 lowered = prefill_j.lower(p_struct, specs["tokens"],
-                                          specs["caches"], *enc)
+                                          specs["caches"], 0, *enc)
             else:
                 pos_struct = jax.ShapeDtypeStruct((), jnp.int32)
                 lowered = decode_j.lower(p_struct, specs["tokens"],

@@ -54,6 +54,8 @@ from ..configs.base import ArchConfig
 from .common import Spec, amm_dot, apply_rope, rmsnorm
 
 __all__ = ["attn_table", "mla_table", "attention", "mla_attention",
+           "mla_project", "mla_absorb_query", "latent_attention",
+           "latent_attention_codes",
            "chunked_attention", "code_cache_dequant", "code_cache_update",
            "decode_attention", "decode_attention_codes",
            "flash_amm_chunked_equiv", "FlashFallbackWarning",
@@ -128,11 +130,15 @@ def attn_table(cfg: ArchConfig) -> Dict[str, Spec]:
 def mla_table(cfg: ArchConfig) -> Dict[str, Spec]:
     d, h = cfg.d_model, cfg.n_heads
     qk_n, qk_r, v_hd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    if cfg.q_lora_rank:
+        q = {"wq_a": Spec((d, cfg.q_lora_rank), ("embed", "q_latent")),
+             "q_a_norm": Spec((cfg.q_lora_rank,), ("q_latent",), "ones"),
+             "wq_b": Spec((cfg.q_lora_rank, h, qk_n + qk_r),
+                          ("q_latent", "heads", "head_dim"))}
+    else:                                   # a direct query projection
+        q = {"wq": Spec((d, h, qk_n + qk_r), ("embed", "heads", "head_dim"))}
     return {
-        "wq_a": Spec((d, cfg.q_lora_rank), ("embed", "q_latent")),
-        "q_a_norm": Spec((cfg.q_lora_rank,), ("q_latent",), "ones"),
-        "wq_b": Spec((cfg.q_lora_rank, h, qk_n + qk_r),
-                     ("q_latent", "heads", "head_dim")),
+        **q,
         "w_dkv": Spec((d, cfg.kv_lora_rank + qk_r), ("embed", "kv_latent")),
         "kv_norm": Spec((cfg.kv_lora_rank,), ("kv_latent",), "ones"),
         "w_uk": Spec((cfg.kv_lora_rank, h, qk_n),
@@ -685,37 +691,161 @@ def attention(p, x, cfg: ArchConfig, *, positions, cache=None, pos=None,
 
 
 # ------------------------------------------------------------ MLA attention
+def mla_project(p, x, cfg: ArchConfig, positions):
+    """(q_nope, q_rope, latent) of rows x (B, S, d): rotated rope queries
+    (B, S, H, rope), the no-position queries (B, S, H, nope), and the
+    cached latent (B, S, kv_lora + rope): the RMS-normed compressed KV
+    beside the rotated shared rope key."""
+    nope = cfg.qk_nope_dim
+    with jax.named_scope(trace.ATTN_PROJ):
+        if cfg.q_lora_rank:         # queries through the low-rank path
+            q_lat = rmsnorm(x @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
+            q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])
+        else:
+            q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+
+        # compressed KV latent + decoupled rope key
+        latent = x @ p["w_dkv"]                       # (B,S,kv_lora+rope)
+        c_kv = rmsnorm(latent[..., :cfg.kv_lora_rank], p["kv_norm"],
+                       cfg.norm_eps)
+        k_rope = apply_rope(latent[..., None, cfg.kv_lora_rank:],
+                            positions, cfg.rope_theta)  # (B,S,1,rope)
+        lat_cat = jnp.concatenate([c_kv, k_rope[..., 0, :]], axis=-1)
+    return q_nope, q_rope, lat_cat
+
+
+def mla_absorb_query(q_nope, q_rope, w_uk):
+    """The absorbed query (B, S, H, kv_lora + rope): ``[q_nope,h W_uk,hᵀ ;
+    q_rope,h]``, whose product with a cached latent ``[c_t ; k_rope,t]``
+    is the head's score before the 1/sqrt(nope + rope) scale."""
+    with jax.named_scope(trace.ATTN_PROJ):
+        return jnp.concatenate(
+            [jnp.einsum("bshk,rhk->bshr", q_nope, w_uk),
+             q_rope.astype(jnp.float32)], axis=-1)
+
+
+def _latent_kv_len(kv_len, b):
+    return jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
+
+
+def latent_attention(q_abs, lat, q_pos, kv_len, *, scale: float,
+                     n_value: int):
+    """Absorbed latent attention over a float latent cache, exact.
+
+    q_abs (B, T, H, L) from ``mla_absorb_query``; lat (B, S, L); q_pos
+    (B, T) each query's position; kv_len scalar or (B,).  Scores are
+    ``q_abs · lat_t * scale`` at positions below ``kv_len`` and at or
+    before the query's; values are the probabilities times the first
+    ``n_value`` latent columns.  Returns (B, T, H, n_value) float32: the
+    heads' value latents, before ``W_uv``.
+    """
+    b, s = lat.shape[:2]
+    kvl = _latent_kv_len(kv_len, b)
+    kpos = jnp.arange(s)
+    live = ((kpos[None, None, :] < kvl[:, None, None])
+            & (kpos[None, None, :] <= q_pos[:, :, None]))      # (B,T,S)
+    lat = jnp.where((kpos[None, :] < kvl[:, None])[..., None],
+                    lat.astype(jnp.float32), 0.0)
+    sc = jnp.einsum("bthl,bsl->bths", q_abs.astype(jnp.float32) * scale,
+                    lat)
+    pr = jax.nn.softmax(jnp.where(live[:, :, None, :], sc, NEG_INF), -1)
+    return jnp.einsum("bths,bsr->bthr", pr, lat[..., :n_value])
+
+
+# token rows of one prefill contracted together (memory of the batched
+# per-block value partials: rows x heads x blocks x n_value int32)
+LATENT_PREFILL_TOKENS = 16
+
+
+def latent_attention_codes(q_abs, codes, scales, q_pos, kv_len, *, amm,
+                           scale: float, n_value: int, n_ctx=None):
+    """Absorbed latent attention straight from the int-code latent cache.
+
+    q_abs (B, T, H, L) float; codes (B, S, L) wl-bit latent codes with
+    one scale per (slot, block) in scales (B, nb); q_pos (B, T); kv_len
+    scalar or (B,).  For each slot and each query token, as in a decode
+    step: the token's H absorbed query rows are quantized together and
+    multiplied with the codes as they are (``bbm_matmul_coded``, the
+    block scales expanded per column), the scores masked to positions
+    below ``kv_len`` and not after the token, the softmax probabilities
+    quantized together and multiplied with the first ``n_value`` code
+    columns block by block (``bbm_matmul_coded_kblocks``).  Codes past
+    ``kv_len`` are zeroed first (a reused slot's stale rows).  A prefill
+    runs its tokens ``LATENT_PREFILL_TOKENS`` at a time.  ``n_ctx``, a
+    static bound on ``kv_len`` where the caller has one (a prefill from
+    position 0), limits the products to the blocks below it: the blocks
+    past it hold zeroed codes, whose scores are masked and whose value
+    partials are 0.  Returns (B, T, H, n_value) float32, the value
+    latents before ``W_uv``.
+    """
+    from ..kernels.bbm_matmul import (bbm_matmul_coded,
+                                      bbm_matmul_coded_kblocks)
+    wl, vbl, kind = amm.attn_lowering
+    block = codes.shape[1] // scales.shape[1]
+    if n_ctx is not None:
+        nb = min(-(-n_ctx // block), scales.shape[1])
+        codes, scales = codes[:, :nb * block], scales[:, :nb]
+    b, s, _ = codes.shape
+    t = q_abs.shape[1]
+    kvl = _latent_kv_len(kv_len, b)
+    kpos = jnp.arange(s)
+
+    def slot(qa, qp, cod, sc, n):
+        live_k = kpos < n
+        cz = jnp.where(live_k[:, None], cod.astype(jnp.int32), 0)
+        cT, cv = cz.T, cz[:, :n_value]
+        cols = jnp.repeat(sc, block)
+
+        def token(args):
+            q_t, p_t = args                                   # (H, L), ()
+            sco = bbm_matmul_coded(q_t.astype(jnp.float32) * scale, cT,
+                                   cols, wl=wl, vbl=vbl, kind=kind)
+            live = live_k & (kpos <= p_t)
+            pr = jax.nn.softmax(jnp.where(live[None, :], sco, NEG_INF),
+                                axis=-1)
+            return bbm_matmul_coded_kblocks(pr, cv, sc, wl=wl, vbl=vbl,
+                                            kind=kind, block=block)
+        if t == 1:
+            return token((qa[0], qp[0]))[None]
+        return jax.lax.map(token, (qa, qp),
+                           batch_size=min(t, LATENT_PREFILL_TOKENS))
+
+    return jax.vmap(slot)(q_abs, q_pos, codes, scales, kvl)
+
+
 def mla_attention(p, x, cfg: ArchConfig, *, positions, cache=None, pos=None,
                   remat_qblock: bool = False, shard_heads: bool = False,
                   causal_skip: bool = False, p_bf16: bool = False,
                   amm=None):
     """DeepSeek-V3 multi-head latent attention.
 
-    The cache stores the compressed latent (B, S, kv_lora + rope_dim); K/V
-    are re-expanded per use (the "naive" formulation — the absorbed-matmul
-    decode optimization is a perf item, not a correctness one).  amm: as
-    in ``attention`` — the score/value products over the re-expanded K/V
-    route through the approximate datapath; the low-rank projections stay
-    exact.  Returns (out, new_cache).
+    The cache stores the compressed latent (B, S, kv_lora + rope_dim).
+    Without a cache (training) K/V are expanded from it (the "naive"
+    formulation).  Against a cache, a model without q-LoRA runs the
+    absorbed form, in prefill and decode alike: ``W_uk`` is folded into
+    the query (``mla_absorb_query``), the scores are its product with the
+    cached latents and the values the probabilities' product with the
+    cached ``c_t``, expanded by ``W_uv`` afterwards
+    (``latent_attention_codes`` on the int-code cache, where the codes
+    feed the Broken-Booth contraction as they are; ``latent_attention``
+    on a float cache).  q-LoRA models keep the expanded form against a
+    cache too, the int-code latent dequantized at read.  amm: as in
+    ``attention`` — the score/value products route through the
+    approximate datapath; the projections stay exact.
+    Returns (out, new_cache).
     """
     b, s, _ = x.shape
     nope, rope_d = cfg.qk_nope_dim, cfg.qk_rope_dim
-    # queries through the low-rank path
-    q_lat = rmsnorm(x @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-
-    # compressed KV latent + decoupled rope key
-    latent = x @ p["w_dkv"]                       # (B,S,kv_lora+rope)
-    c_kv = rmsnorm(latent[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(latent[..., None, cfg.kv_lora_rank:],
-                        positions, cfg.rope_theta)  # (B,S,1,rope)
-    lat_cat = jnp.concatenate([c_kv, k_rope[..., 0, :]], axis=-1)
+    q_nope, q_rope, lat_cat = mla_project(p, x, cfg, positions)
 
     if cache is not None and s > 1 and jnp.ndim(pos) == 1:
         raise ValueError("multi-token prefill needs a scalar position; "
                          "per-slot position vectors are decode-only")
+    if cache is not None and not cfg.q_lora_rank:
+        return _mla_absorbed(p, q_nope, q_rope, lat_cat, cfg, cache=cache,
+                             positions=positions, pos=pos, amm=amm)
     if cache is not None and "lat_codes" in cache:
         # int-code latent cache: the compressed latent is quantized at
         # write (frozen codes, first-touch block scales) and dequantized
@@ -772,4 +902,43 @@ def mla_attention(p, x, cfg: ArchConfig, *, positions, cache=None, pos=None,
                                 causal_skip=causal_skip, p_bf16=p_bf16,
                                 amm=amm)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    return y, new_cache
+
+
+def _mla_absorbed(p, q_nope, q_rope, lat_cat, cfg: ArchConfig, *, cache,
+                  positions, pos, amm):
+    """The absorbed form against a cache (``mla_attention``)."""
+    s = lat_cat.shape[1]
+    r = cfg.kv_lora_rank
+    scale = 1.0 / ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5)
+    q_abs = mla_absorb_query(q_nope, q_rope, p["w_uk"])
+    kv_len = pos + s
+    if "lat_codes" in cache:
+        if amm is None or amm.attn_lowering is None:
+            raise ValueError("int-code KV cache requires an active "
+                             "Booth-family bitexact amm attention lowering")
+        with jax.named_scope(trace.ATTN_LATENT_CACHE):
+            lc, ls = code_cache_update(
+                cache["lat_codes"][:, :, None, :],
+                cache["lat_scale"][..., None], lat_cat[:, :, None, :], pos,
+                wl=amm.attn_lowering[0])
+            new_cache = {"lat_codes": lc[:, :, 0, :],
+                         "lat_scale": ls[..., 0]}
+            o_lat = latent_attention_codes(
+                q_abs, new_cache["lat_codes"], new_cache["lat_scale"],
+                positions, kv_len, amm=amm, scale=scale, n_value=r,
+                n_ctx=kv_len if isinstance(kv_len, int) else None)
+    else:
+        if amm is not None:
+            raise ValueError("approximate latent attention reads the "
+                             "int-code latent cache (kv_codes); a float "
+                             "latent cache runs its products exact")
+        new_lat = _cache_put(cache["latent"],
+                             lat_cat.astype(cache["latent"].dtype), pos)
+        new_cache = {"latent": new_lat}
+        o_lat = latent_attention(q_abs, new_lat, positions, kv_len,
+                                 scale=scale, n_value=r)
+    with jax.named_scope(trace.ATTN_PROJ):
+        out = jnp.einsum("bshr,rhk->bshk", o_lat, p["w_uv"])
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache

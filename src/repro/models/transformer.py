@@ -280,13 +280,18 @@ def _dense_block(p, h, cfg, rt, key, *, positions, cache=None, pos=None,
 
 def _moe_block(p, h, cfg, rt, key, *, positions, cache=None, pos=None,
                planes=None):
+    """(h, new_cache, aux, expert ids): against a cache (serving) the
+    expert layer runs dropless and returns each token's expert ids
+    (B, S, k); without one the ids are None."""
     h, new_cache = _attn_block(p, h, cfg, rt, positions=positions,
                                cache=cache, pos=pos)
-    y, aux = moe_apply(p["moe"], rmsnorm(h, p["mlp_norm"], cfg.norm_eps),
-                       cfg, amm=rt.amm, key=key,
-                       gather_weights=rt.moe_gather_weights,
-                       planes=(planes or {}).get("moe"))
-    return h + y.astype(h.dtype), new_cache, aux
+    out = moe_apply(p["moe"], rmsnorm(h, p["mlp_norm"], cfg.norm_eps),
+                    cfg, amm=rt.amm, key=key,
+                    gather_weights=rt.moe_gather_weights,
+                    planes=(planes or {}).get("moe"),
+                    serving=cache is not None)
+    y, aux, ids = out if cache is not None else out + (None,)
+    return h + y.astype(h.dtype), new_cache, aux, ids
 
 
 def _ssm_block(p, h, cfg, rt, *, state=None, conv_state=None):
@@ -308,7 +313,10 @@ def lm_apply(params, cfg: ArchConfig, rt: ModelRuntime, tokens, *,
     amm_planes: optional ``lm_amm_planes`` cache — the bitexact
     approximate-matmul weight decode hoisted out of the step (serving:
     built once at engine construction).  Bit-identical to passing None.
-    Returns (logits, aux_losses, new_caches).
+    Returns (logits, aux, new_caches); ``aux["moe_aux"]`` is the MoE
+    load-balance loss, and against caches a MoE model's
+    ``aux["expert_ids"]`` (moe layers, B, S, k) holds the experts each
+    token was routed to.
     """
     if rng is None:
         rng = jax.random.key(0)
@@ -322,6 +330,7 @@ def lm_apply(params, cfg: ArchConfig, rt: ModelRuntime, tokens, *,
         off = off[:, None]
     positions = (jnp.arange(s)[None, :] + off) * jnp.ones((b, 1), jnp.int32)
     aux_total = jnp.float32(0.0)
+    aux_extra: Dict[str, Any] = {}
     new_caches: Dict[str, Any] = {}
     decode = mode == "decode"
 
@@ -438,19 +447,21 @@ def lm_apply(params, cfg: ArchConfig, rt: ModelRuntime, tokens, *,
             hh, key, aux = carry
             p_l, cache_l, planes_l = xs
             key, sub = jax.random.split(key)
-            hh, new_c, aux_l = _moe_block(p_l, hh, cfg, rt, sub,
-                                          positions=positions,
-                                          cache=cache_l, pos=pos,
-                                          planes=planes_l)
-            return (hh, key, aux + aux_l), new_c
+            hh, new_c, aux_l, ids = _moe_block(p_l, hh, cfg, rt, sub,
+                                               positions=positions,
+                                               cache=cache_l, pos=pos,
+                                               planes=planes_l)
+            return (hh, key, aux + aux_l), (new_c, ids)
 
         k_pref = cfg.first_k_dense
         cache_xs = (jax.tree.map(lambda c: c[k_pref:], caches)
                     if caches is not None else None)
-        (h, _, aux_total), new_kv = jax.lax.scan(
+        (h, _, aux_total), (new_kv, expert_ids) = jax.lax.scan(
             maybe_remat(layer), (h, rng, aux_total),
             (params["layers"], cache_xs, amm_planes.get("layers")))
         if caches is not None:
+            # (moe layers, B, S, k): each token's experts, layer by layer
+            aux_extra["expert_ids"] = expert_ids
             # re-assemble the full layer-stacked cache (prefix + scanned)
             stacked_prefix = jax.tree.map(
                 lambda *xs: jnp.stack(xs, axis=0), *prefix_new) \
@@ -526,7 +537,7 @@ def lm_apply(params, cfg: ArchConfig, rt: ModelRuntime, tokens, *,
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
-    return logits, {"moe_aux": aux_total}, new_caches
+    return logits, {"moe_aux": aux_total, **aux_extra}, new_caches
 
 
 def lm_loss(params, cfg: ArchConfig, rt: ModelRuntime, tokens, labels, *,
@@ -555,8 +566,8 @@ def lm_loss(params, cfg: ArchConfig, rt: ModelRuntime, tokens, labels, *,
         b, s = tokens.shape
         positions = jnp.arange(s)[None, :] * jnp.ones((b, 1), jnp.int32)
         mtp_rng = rng if rng is not None else jax.random.key(1)
-        h_m, _, _aux = _moe_block(p_m, h_m, cfg, rt, mtp_rng,
-                                  positions=positions)
+        h_m, _, _aux, _ = _moe_block(p_m, h_m, cfg, rt, mtp_rng,
+                                     positions=positions)
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits_m = (rmsnorm(h_m, params["final_norm"], cfg.norm_eps)

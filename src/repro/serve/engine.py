@@ -2,8 +2,12 @@
 
 ``make_serve_fns`` builds the two jitted entry points the dry-run lowers:
 
-  prefill_fn(params, tokens, caches)        -> (logits_last, caches)
+  prefill_fn(params, tokens, caches[, start]) -> (logits_last, caches)
   decode_fn(params, tokens_1, caches, pos)  -> (logits, caches)
+
+A MoE model's programs return a third output, each token's expert ids
+per MoE layer, (moe layers, B, S, k) int32, left on the device; the
+scheduler passes it by (a caller that wraps the programs can keep it).
 
 The KV caches are sharded by logical rules (batch over data, kv_heads over
 model, MLA latent over seq on model — see parallel/logical.py), and decode
@@ -127,8 +131,10 @@ def make_serve_fns(cfg: ArchConfig, rt: ModelRuntime, mesh: Mesh, *,
     accepts a scalar or a (B,) per-slot vector either way (the vector is
     replicated — it is B int32s).  Continuous-mode prefill calls the
     prefill fn on batch-1 slot slices, retracing once per distinct prompt
-    length (NamedShardings are shape-agnostic, so the same jitted fn
-    serves both the warmup full-batch prefill and the slot slices).
+    length, and per first position ``start`` (static, positional) where
+    the scheduler feeds prompts in chunks (NamedShardings are
+    shape-agnostic, so the same jitted fn serves both the warmup
+    full-batch prefill and the slot slices).
     """
     from ..models import lm_logical_axes, lm_table
     if kv_codes and rt.amm.attn_lowering is None:
@@ -144,25 +150,35 @@ def make_serve_fns(cfg: ArchConfig, rt: ModelRuntime, mesh: Mesh, *,
     b_sh = NamedSharding(mesh, batch_pspec(mesh, batch))
     scalar = NamedSharding(mesh, P())
 
-    def prefill(params, tokens, caches, encoder_embeds=None):
-        logits, _, new_caches = lm_apply(
-            params, cfg, rt, tokens, mode="decode", caches=caches,
-            pos=jnp.int32(0), encoder_embeds=encoder_embeds,
-            amm_planes=amm_planes)
+    routing = cfg.family == "moe"
+
+    def outputs(logits, aux, new_caches):
+        if routing:
+            return logits[:, -1], new_caches, aux["expert_ids"]
         return logits[:, -1], new_caches
+
+    def prefill(params, tokens, caches, start=0, encoder_embeds=None):
+        # the tokens from position ``start`` on (static: 0 unless the
+        # scheduler feeds the prompt in chunks); a MoE model takes it as a
+        # Python int, so that its latent attention reads the prompt's
+        # positions
+        return outputs(*lm_apply(
+            params, cfg, rt, tokens, mode="decode", caches=caches,
+            pos=start if routing else jnp.int32(start),
+            encoder_embeds=encoder_embeds, amm_planes=amm_planes))
 
     def decode(params, tokens, caches, pos, encoder_embeds=None):
-        logits, _, new_caches = lm_apply(
+        return outputs(*lm_apply(
             params, cfg, rt, tokens, mode="decode", caches=caches, pos=pos,
-            encoder_embeds=encoder_embeds, amm_planes=amm_planes)
-        return logits[:, -1], new_caches
+            encoder_embeds=encoder_embeds, amm_planes=amm_planes))
 
     enc_sh = (b_sh,) if cfg.is_encoder_decoder else ()
+    out_sh = (b_sh, c_sh) + ((scalar,) if routing else ())
     prefill_j = jax.jit(prefill, in_shardings=(p_sh, b_sh, c_sh) + enc_sh,
-                        out_shardings=(b_sh, c_sh))
+                        out_shardings=out_sh, static_argnums=(3,))
     decode_j = jax.jit(decode,
                        in_shardings=(p_sh, b_sh, c_sh, scalar) + enc_sh,
-                       out_shardings=(b_sh, c_sh),
+                       out_shardings=out_sh,
                        donate_argnums=(2,))
     return prefill_j, decode_j
 
@@ -392,10 +408,14 @@ class Scheduler:
       * ``continuous=True``: per-step admission into any free slot (FIFO,
         at most ``max_prefills_per_step`` admissions per step so a queue
         of long prompts cannot starve resident decodes), the prompt
-        prefilled as ONE batch-1 dispatch against the slot's cache slice,
-        then per-slot-position batched decode over all residents; slots
-        are evicted and their cache slice zeroed for reuse on completion
-        or failure.  When the per-row arithmetic is row-independent —
+        prefilled as ONE batch-1 dispatch against the slot's cache slice
+        (or, with ``prefill_chunk``, ``prefill_chunk`` tokens a step: one
+        prompt at a time, its slice held aside until the last chunk, so
+        no prompt holds a step for more than one chunk and the gap
+        between a resident's tokens no longer depends on the admitted
+        prompt's length), then per-slot-position batched decode over all
+        residents; slots are evicted and their cache slice zeroed for
+        reuse on completion or failure.  When the per-row arithmetic is row-independent —
         exact matmuls, or attention-side amm routing whose ``amm_dot``
         vmaps a fresh quantization scale per (slot, head) slice — a
         request's token stream is identical whether it shares the batch
@@ -447,6 +467,7 @@ class Scheduler:
                  prefill_fn=None, continuous: bool = False,
                  kv_codes: bool = False, kv_block: int = KV_BLOCK,
                  max_prefills_per_step: int = 1,
+                 prefill_chunk: Optional[int] = None,
                  guard: Optional[GuardConfig] = None, max_retries: int = 0,
                  backoff: float = 0.0, backoff_cap: float = 1.0):
         self.cfg, self.rt, self.params = cfg, rt, params
@@ -473,6 +494,13 @@ class Scheduler:
         self.continuous = continuous
         self.kv_codes = kv_codes
         self.max_prefills_per_step = max_prefills_per_step
+        if prefill_chunk is not None and (not continuous
+                                          or prefill_chunk < 1):
+            raise ValueError("prefill_chunk needs continuous=True and at "
+                             f"least one token, got {prefill_chunk}")
+        self.prefill_chunk = prefill_chunk
+        # (slot, its cache slice, tokens fed) of a prompt fed in chunks
+        self._chunking = None
         self._bax = batch_axis_tree(
             cache_logical_axes(cfg, kv_codes=kv_codes))
         self.queue: List[Request] = []
@@ -524,6 +552,12 @@ class Scheduler:
                 req._steps = 0
 
     @staticmethod
+    def _unpack(out):
+        """(logits, caches) of a serving program's outputs (a MoE
+        program's third, its expert ids, is not read here)."""
+        return out[0], out[1]
+
+    @staticmethod
     def _pos_arr(pos):
         """Decode position operand: scalar (flush mode) or (B,) vector."""
         return jnp.asarray(pos, jnp.int32)
@@ -534,10 +568,10 @@ class Scheduler:
             caches=c, pos=self._pos_arr(q), amm_planes=self.amm_planes)
         return logits[:, -1], new_c
 
-    def _default_prefill(self, p, t, c):
+    def _default_prefill(self, p, t, c, start=0):
         logits, _, new_c = lm_apply(
             p, self.cfg, self.rt, jnp.asarray(t), mode="decode",
-            caches=c, pos=jnp.int32(0), amm_planes=self.amm_planes)
+            caches=c, pos=jnp.int32(start), amm_planes=self.amm_planes)
         return logits[:, -1], new_c
 
     def _fail(self, i: int, reason: str):
@@ -585,8 +619,9 @@ class Scheduler:
             backup = self._snapshot() if donating and self.max_retries \
                 else None
             try:
-                logits, self.caches = fn(self.params, jnp.asarray(toks),
-                                         self.caches, self._pos_arr(pos))
+                logits, self.caches = self._unpack(fn(
+                    self.params, jnp.asarray(toks), self.caches,
+                    self._pos_arr(pos)))
                 return logits, live
             except Exception as e:
                 last = e
@@ -612,8 +647,8 @@ class Scheduler:
         toks = toks.copy()
         for i in poison:
             toks[i] = 0
-        logits, self.caches = fn(self.params, jnp.asarray(toks),
-                                 self.caches, self._pos_arr(pos))
+        logits, self.caches = self._unpack(fn(
+            self.params, jnp.asarray(toks), self.caches, self._pos_arr(pos)))
         return logits, live
 
     def _guard_slots(self, logits, toks, pos, pre_caches, live) -> List[int]:
@@ -700,18 +735,32 @@ class Scheduler:
         generated token falls out of the prefill itself.  Empty prompts
         prefill the single pad token 0, matching flush-mode semantics
         (decoding starts from token 0).
+
+        With ``prefill_chunk`` one call feeds the next chunk only, from
+        the position the last one reached; until the last chunk the slice
+        stays aside in ``_chunking`` (the slot's row of the decode steps
+        meanwhile is a dead row, whose write the slice replaces).
         """
         req = self.slots[i]
         toks = list(req.prompt) or [0]
         fn = self.prefill_fn or self._default_prefill
-        with trace.span("sched.slot_take"):
-            sub = slot_take(self.caches, self._bax, i)
+        if self._chunking is None:
+            with trace.span("sched.slot_take"):
+                sub = slot_take(self.caches, self._bax, i)
+            start = 0
+        else:
+            _, sub, start = self._chunking
+            self._chunking = None
+        end, at = len(toks), ()
+        if self.prefill_chunk is not None:
+            end, at = min(end, start + self.prefill_chunk), (start,)
         last = None
         with trace.span("sched.prefill"):
             for attempt in range(self.max_retries + 1):
                 try:
-                    logits, sub = fn(self.params,
-                                     jnp.asarray([toks], jnp.int32), sub)
+                    logits, sub = self._unpack(fn(
+                        self.params, jnp.asarray([toks[start:end]], jnp.int32),
+                        sub, *at))
                     break
                 except Exception as e:
                     last = e
@@ -723,11 +772,14 @@ class Scheduler:
             else:
                 self._fail(i, f"prefill failed: {last!r}")
                 return
+        self.stats["decoded"] += end - start
+        if end < len(toks):
+            self._chunking = (i, sub, end)
+            return
         with trace.span("sched.slot_put"):
             self.caches = slot_put(self.caches, self._bax, sub, i)
         self.pos[i] = len(toks)
         self.stats["prefills"] += 1
-        self.stats["decoded"] += len(toks)
         req._pending = []
         with trace.span("sched.first_token"):
             req.out.append(int(np.asarray(jnp.argmax(logits, axis=-1)
@@ -746,11 +798,18 @@ class Scheduler:
         previous occupant) before prefilling.  Freshly admitted slots join
         the same step's decode — their (token, position) trajectory is
         self-contained, so step alignment cannot change any request's
-        stream.
+        stream.  With ``prefill_chunk`` a step feeds the prompt in flight
+        its next chunk, or else admits as above, each admission feeding
+        its first chunk, and stops at a prompt left in flight; the slot
+        joins the decode once its last chunk is in.
         """
         admitted = 0
+        if self._chunking is not None:      # the prompt in flight goes on
+            self._prefill_slot(self._chunking[0])
+            admitted = self.max_prefills_per_step
         for i in range(len(self.slots)):
-            if not self.queue or admitted >= self.max_prefills_per_step:
+            if not self.queue or admitted >= self.max_prefills_per_step \
+                    or self._chunking is not None:
                 break
             if self.slots[i] is None:
                 req = self.queue.pop(0)
@@ -763,9 +822,12 @@ class Scheduler:
                         self.caches = reset_slot(self.caches, self._bax, i)
                     self._prefill_slot(i)    # may fail or finish the slot
                 admitted += 1
-        live = [i for i, s in enumerate(self.slots) if s is not None]
+        feeding = self._chunking[0] if self._chunking is not None else None
+        live = [i for i, s in enumerate(self.slots)
+                if s is not None and i != feeding]
+        fed = int(feeding is not None)     # a live request, not yet a row
         if not live:
-            return 0
+            return fed
         self.stats["steps"] += 1
         toks = np.zeros((len(self.slots), 1), np.int32)
         for i in live:
@@ -775,7 +837,7 @@ class Scheduler:
         audit = (self.guard is not None and self.guard.budget_active
                  and self.stats["steps"] % self.guard.budget_every == 0)
         pre_caches = self._snapshot() if audit else None
-        n_live = len(live)
+        n_live = len(live) + fed
         with trace.span("sched.decode"):
             logits, live = self._decode_isolated(fn, toks, pos, live)
         if logits is None:

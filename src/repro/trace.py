@@ -31,7 +31,8 @@ from typing import Dict
 
 import jax
 
-__all__ = ["PREFIX", "SCOPES", "span", "recorded", "clear", "gc_spans"]
+__all__ = ["PREFIX", "SCOPES", "MOE_SCOPES", "span", "recorded", "clear",
+           "gc_spans"]
 
 PREFIX = "repro."
 
@@ -44,6 +45,13 @@ ATTN_CODE_CACHE = "attn.code_cache"  # int-code cache write + attention
 LM_HEAD = "lm.head"              # final norm and the LM head
 SCOPES = (AMM_WEIGHT_DECODE, AMM_CONTRACT, AMM_STE_EXACT, ATTN_PROJ,
           ATTN_CODE_CACHE, LM_HEAD)
+# the latent-attention and expert layers' scopes (MLA + MoE families)
+ATTN_LATENT_CACHE = "attn.latent_cache"  # latent cache write + both latent
+                                         # products (scores, values)
+MOE_ROUTE = "moe.route"          # router, expert choice and dispatch
+MOE_EXPERTS = "moe.experts"      # the routed experts this device holds
+MOE_SHARED = "moe.shared"        # the shared experts
+MOE_SCOPES = (ATTN_LATENT_CACHE, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)
 
 _table: Dict[str, list] = {}      # name -> [calls, seconds, self seconds]
 _lock = threading.Lock()

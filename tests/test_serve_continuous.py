@@ -274,3 +274,100 @@ def test_kv_codes_rejects_exact_budget_guard(lm):
     guard = GuardConfig(budget_abs=0.0, budget_every=1)
     with pytest.raises(ValueError, match="guard budget audit"):
         Scheduler(cfg, rt, params, 1, MAX_LEN, kv_codes=True, guard=guard)
+
+
+def _fake_programs(vocab):
+    """(prefill, decode, calls): serving programs that log their calls;
+    a prefill adds 10 to every cache leaf and predicts its last position
+    + 1, a decode adds 1 and predicts each row's token + 1."""
+    calls = []
+
+    def onehot(idx):
+        return jax.nn.one_hot(jnp.asarray(idx), vocab)
+
+    def prefill(p, tokens, caches, start=0):
+        calls.append(("prefill", np.asarray(tokens)[0].tolist(), start))
+        caches = jax.tree.map(lambda c: c + 10, caches)
+        return onehot([start + tokens.shape[1]]), caches
+
+    def decode(p, tokens, caches, pos):
+        calls.append(("decode", np.asarray(tokens)[:, 0].tolist(),
+                      np.asarray(pos).tolist()))
+        caches = jax.tree.map(lambda c: c + 1, caches)
+        return onehot(np.asarray(tokens)[:, 0] + 1), caches
+    return prefill, decode, calls
+
+
+def test_prefill_chunks_feed_one_prompt_a_step(lm):
+    """``prefill_chunk``: a prompt is fed a chunk a step from the position
+    the last chunk reached, one prompt at a time; a resident gains one
+    token every step meanwhile; the fed slot is a dead row (token 0,
+    position 0) of the decode steps until its last chunk, whose slice
+    then replaces whatever those steps wrote into the slot."""
+    cfg, rt, params = lm
+    prefill, decode, calls = _fake_programs(cfg.vocab)
+    sched = Scheduler(cfg, rt, params, 2, MAX_LEN, continuous=True,
+                      decode_fn=decode, prefill_fn=prefill, prefill_chunk=2)
+    resident = Request(rid=0, prompt=[1], max_new=10)
+    fed = Request(rid=1, prompt=[3, 4, 5, 6, 7], max_new=2)
+    sched.submit(resident)
+    sched.step()
+    calls.clear()
+    sched.submit(fed)
+    for n in range(3):
+        before = len(resident.out)
+        sched.step()
+        assert len(resident.out) == before + 1
+        assert fed.out == ([] if n < 2 else [5, 6])
+    assert [c for c in calls if c[0] == "prefill"] == [
+        ("prefill", [3, 4], 0), ("prefill", [5, 6], 2), ("prefill", [7], 4)]
+    decodes = [c for c in calls if c[0] == "decode"]
+    assert [d[1][1] for d in decodes[:2]] == [0, 0]      # dead row
+    assert [d[2][1] for d in decodes[:2]] == [0, 0]
+    assert decodes[2][1][1] == 5 and decodes[2][2][1] == 5
+    # the fed slot: 3 chunks' writes, then the one decode step since
+    # (its first token 5 came from the last chunk, 6 from that step)
+    leaf = jax.tree.leaves(sched.caches)[0]
+    assert float(leaf.reshape(leaf.shape[0], 2, -1)[0, 1, 0]) == 31
+    assert sched.stats["prefills"] == 2
+
+
+def test_prefill_chunks_admit_several_whole_prompts_a_step(lm):
+    """With ``max_prefills_per_step`` above 1, a step admits prompts of
+    one chunk until the cap, and stops at a prompt left in flight; the
+    next step only feeds that prompt on."""
+    cfg, rt, params = lm
+    prefill, decode, calls = _fake_programs(cfg.vocab)
+    sched = Scheduler(cfg, rt, params, 4, MAX_LEN, continuous=True,
+                      decode_fn=decode, prefill_fn=prefill, prefill_chunk=2,
+                      max_prefills_per_step=3)
+    for i, prompt in enumerate([[1, 2], [3], [4, 5, 6], [7]]):
+        sched.submit(Request(rid=i, prompt=prompt, max_new=4))
+    sched.step()
+    assert [c[1:] for c in calls if c[0] == "prefill"] == [
+        ([1, 2], 0), ([3], 0), ([4, 5], 0)]
+    calls.clear()
+    sched.step()
+    assert [c[1:] for c in calls if c[0] == "prefill"] == [([6], 2)]
+    calls.clear()
+    sched.step()
+    assert [c[1:] for c in calls if c[0] == "prefill"] == [([7], 0)]
+
+
+def test_prefill_chunks_drain_a_lone_prompt(lm):
+    """A prompt in flight counts as live: a caller that steps while
+    ``step()`` returns non-zero drains a lone chunked request."""
+    cfg, rt, params = lm
+    prefill, decode, calls = _fake_programs(cfg.vocab)
+    sched = Scheduler(cfg, rt, params, 2, MAX_LEN, continuous=True,
+                      decode_fn=decode, prefill_fn=prefill, prefill_chunk=2)
+    req = Request(rid=0, prompt=[3, 4, 5, 6, 7], max_new=3)
+    sched.submit(req)
+    assert _drain(sched) == 4   # 3 feed the chunks (the last decodes too)
+    assert req.done and req.out == [5, 6, 7]
+
+
+def test_prefill_chunk_requires_continuous_mode(lm):
+    cfg, rt, params = lm
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        Scheduler(cfg, rt, params, 1, MAX_LEN, prefill_chunk=4)
