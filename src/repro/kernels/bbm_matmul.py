@@ -55,15 +55,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.booth import num_pp_rows
 from ..core.faults import apply_acc_fault, apply_plane_faults
+from ..trace import AMM_PV_DOT, AMM_PV_ROWS
 from .booth_rows import (amm_chunk_len, bbm_rows_product_precoded,
-                         booth_high_value, booth_precode,
-                         f32_exact_chunk_len, num_corr_rows, resolve_form,
-                         scaled_trunc_rows, signed_digit, split_signed)
+                         bbm_rows_product_scaled, booth_digit_rows,
+                         booth_high_value, booth_precode, f32_exact_chunk_len,
+                         num_corr_rows, resolve_form, scaled_trunc_rows,
+                         signed_digit, split_signed)
 from .ref import amm_quantize
 
 __all__ = ["bbm_matmul_kernel", "bbm_matmul", "bbm_matmul_coded",
            "bbm_matmul_coded_kblocks", "bbm_matmul_dynamic",
-           "bbm_matmul_precoded", "bbm_matmul_scaled", "dot_scaled_chunked"]
+           "bbm_matmul_precoded", "bbm_matmul_scaled", "dot_scaled_chunked",
+           "pv_form"]
 
 # auto-form only: above this many int32 elements the shift > vbl residual
 # branch's (M, K, N) per-product temporary stops being a fair trade against
@@ -367,6 +370,24 @@ def bbm_matmul_coded(a, b_codes, s_b, *, wl: int, vbl: int, kind: int = 0):
     return (yq * (s_a * s_b)).astype(a.dtype)
 
 
+def pv_form(block: int, wl: int, vbl: int) -> str:
+    """"rows" or "dot": the form ``bbm_matmul_coded_kblocks`` forms a
+    K-block partial of the value product in, for blocks of ``block``
+    positions at word length ``wl`` and ``vbl`` nullified bits.
+
+    Decided from static shapes alone, so every caller at a given shape
+    runs the same program, and both forms give the same integers (the
+    choice never changes a bit).  A block within ``amm_chunk_len`` takes
+    the multiply-free rows form: its sum over the block's positions stays
+    inside int32, and the v5e compiler's cost model gives it under a
+    fifth of the batched dot form's bytes and about three fifths of its
+    operations at both served block shapes (PERF.md section 7 row 12).
+    A longer block keeps the dot form, whose chunks stay inside the int32
+    envelope where the rows sum would not.
+    """
+    return "rows" if block <= amm_chunk_len(wl, vbl) else "dot"
+
+
 def bbm_matmul_coded_kblocks(a, b_codes, s_b, *, wl: int, vbl: int,
                              kind: int = 0, block: int):
     """``bbm_matmul_coded`` with per-K-block ``b`` scales (the PV product).
@@ -374,45 +395,80 @@ def bbm_matmul_coded_kblocks(a, b_codes, s_b, *, wl: int, vbl: int,
     The V cache quantizes rows in groups of ``block`` positions, so the
     contraction cannot descale once at the end: each K-block's integer
     partial descales by its own ``s_a * s_b[j]`` before the float32
-    combine.  All ``K // block`` blocks contract at once: the codes are
-    regrouped to (nb, M, block) x (nb, block, N), and one
-    ``booth_precode`` + ``bbm_matmul_scaled`` vmapped over the block axis
-    gives every block's exact integer partial — the same contractions as
-    a per-block loop, as one batched call, so the op count does not grow
-    with the number of blocks.  The digit planes are decoded inside the
-    vmap so they come out block axis first (decoding the whole slice and
-    then moving the block axis in front costs a transpose of every
-    plane).  ``a``'s dynamic scale is derived once over the whole (M, K)
-    slice, matching what the dynamic entry would compute for the same
-    ``a``.
+    combine.  All ``K // block`` blocks contract at once, so the op count
+    does not grow with the number of blocks: the codes are regrouped to
+    (nb, M, block) x (nb, block, N) and every block's exact integer
+    partial at the ``2^-vbl`` product scale comes out of one of two forms
+    (``pv_form``, chosen from the shapes):
 
-    The descaled blocks are then combined by a chain of adds in block
-    order.  Float addition order is part of the bitwise contract with
+      rows  the V codes' digit planes decoded per block and the
+            multiply-free Broken-Booth rows (``bbm_rows_product_scaled``)
+            formed over (nb, M, block, N), summed over the block's
+            positions in int32 — no dot at all, on the VPU;
+      dot   ``booth_precode`` + ``bbm_matmul_scaled`` vmapped over the
+            block axis: the dot form's contractions, batched.  The digit
+            planes are decoded inside the vmap so they come out block
+            axis first (decoding the whole slice and then moving the
+            block axis in front costs a transpose of every plane).
+
+    Both partials are the same int32 (a block within
+    ``booth_rows.amm_chunk_len`` is one exact chunk of the dot form; a
+    longer one keeps the dot form), cast to float32 at ``2^vbl``.  ``a``'s
+    dynamic scale is derived once over the whole (M, K) slice, matching
+    what the dynamic entry would compute for the same ``a``.  The product
+    runs under the ``amm.pv_rows`` or ``amm.pv_dot`` scope.
+
+    The descaled blocks are then added one at a time in block order, in
+    a loop over the blocks (``lax.scan``).  Float addition order and
+    rounding are part of the bitwise contract with
     ``ref.amm_coded_kblocks_ref``: a reduction over the block axis
     (``jnp.sum``) is free to reassociate the float adds (pairwise or
-    tree order) and would break it.  With a single block this reduces
-    exactly to ``bbm_matmul_coded``.
+    tree order), and an unrolled chain of adds fuses with the descales,
+    where a backend may contract a multiply and an add into one rounding
+    (XLA:CPU does at 4-8 blocks); either would break it.  With a single
+    block this reduces exactly to ``bbm_matmul_coded``.
 
     a: (M, K) float; b_codes: (K, N) codes with K % block == 0;
     s_b: (K // block,) f32.
     """
-    kk, nn = b_codes.shape
+    kk = b_codes.shape[0]
     if kk % block:
         raise ValueError(f"K={kk} not a multiple of block={block}")
+    form = pv_form(block, wl, vbl)
+    with jax.named_scope(AMM_PV_ROWS if form == "rows" else AMM_PV_DOT):
+        return _coded_kblocks(a, b_codes, s_b, wl=wl, vbl=vbl, kind=kind,
+                              block=block, form=form)
+
+
+def _coded_kblocks(a, b_codes, s_b, *, wl, vbl, kind, block, form):
+    """``bbm_matmul_coded_kblocks`` in the given form (same bits)."""
+    kk, nn = b_codes.shape
     nb = kk // block
     aq, s_a = amm_quantize(a, wl)
     aq = aq.reshape(aq.shape[0], nb, block).transpose(1, 0, 2)
     b_codes = jnp.asarray(b_codes, jnp.int32).reshape(nb, block, nn)
+    if form == "rows":
+        _, x_s = split_signed(aq, wl)
+        # per-row digits, unstacked, so each row's fuses into the product
+        mag, neg = booth_digit_rows(b_codes, wl)          # R x (nb, block, N)
+        prod = bbm_rows_product_scaled(
+            x_s[:, :, :, None], [m[:, None] for m in mag],
+            [g[:, None] for g in neg],
+            wl=wl, vbl=vbl, kind=kind)                    # (nb, M, block, N)
+        yq = jnp.sum(prod, axis=2, dtype=jnp.int32)
+        yq = yq.astype(jnp.float32) * float(1 << vbl)
+    else:
+        def block_partial(x, codes):
+            mag, neg = booth_precode(codes, wl)
+            return bbm_matmul_scaled(x, mag, neg, wl=wl, vbl=vbl, kind=kind)
 
-    def block_partial(x, codes):
-        mag, neg = booth_precode(codes, wl)
-        return bbm_matmul_scaled(x, mag, neg, wl=wl, vbl=vbl, kind=kind)
-
-    yq = jax.vmap(block_partial)(aq, b_codes)                # (nb, M, N)
+        yq = jax.vmap(block_partial)(aq, b_codes)         # (nb, M, N)
     parts = yq * (s_a * jnp.asarray(s_b, jnp.float32))[:, None, None]
-    acc = parts[0]
-    for bi in range(1, nb):
-        acc = acc + parts[bi]
+    # the adds run in a loop over the blocks, apart from the descales: in
+    # one fused loop a backend may contract a descale and the next add
+    # into an FMA, one rounding where the oracle has two (XLA:CPU does at
+    # 4-8 blocks), and a barrier between them is dropped before fusion
+    acc, _ = jax.lax.scan(lambda acc, p: (acc + p, None), parts[0], parts[1:])
     return acc.astype(a.dtype)
 
 

@@ -72,11 +72,12 @@ import jax.numpy as jnp
 from ..core.booth import num_pp_rows
 
 __all__ = ["amm_chunk_len", "bbm_rows_product", "bbm_rows_product_precoded",
-           "bbm_rows_product_dotform", "booth_correction",
-           "booth_high_value", "booth_precode", "booth_precode_faulty",
-           "booth_value", "dotform_scaled_bound", "f32_exact_chunk_len",
-           "num_corr_rows", "resolve_form", "scaled_trunc_rows",
-           "signed_digit", "split_signed"]
+           "bbm_rows_product_dotform", "bbm_rows_product_scaled",
+           "booth_correction", "booth_digit_rows", "booth_high_value",
+           "booth_precode", "booth_precode_faulty", "booth_value",
+           "dotform_scaled_bound", "f32_exact_chunk_len", "num_corr_rows",
+           "resolve_form", "scaled_trunc_rows", "signed_digit",
+           "split_signed"]
 
 
 def split_signed(x, wl: int):
@@ -97,6 +98,19 @@ def booth_precode(bu, wl: int):
     ``mag = 0, neg = 1``).  Call once per constant operand and feed the
     planes to ``bbm_rows_product_precoded``.
     """
+    mags, negs = booth_digit_rows(bu, wl)
+    return jnp.stack(mags), jnp.stack(negs)
+
+
+def booth_digit_rows(bu, wl: int):
+    """``booth_precode``'s planes as two lists of ``wl//2`` arrays.
+
+    For a caller that decodes inside the same program that consumes the
+    digits: unstacked, each row's (mag, neg) can fuse into its consumer
+    where a stacked plane array is materialized whole first.  Every
+    consumer of the planes indexes them by row, so the lists stand in
+    for the stacked arrays.
+    """
     bu = jnp.asarray(bu, jnp.int32) & ((1 << wl) - 1)
     mags, negs = [], []
     prev_hi = None
@@ -109,7 +123,7 @@ def booth_precode(bu, wl: int):
         d = -2 * b_hi + b_mid + b_lo
         mags.append(jnp.abs(d))
         negs.append(b_hi)
-    return jnp.stack(mags), jnp.stack(negs)
+    return mags, negs
 
 
 def booth_precode_faulty(bu, wl: int, fault=None, *, vbl: int = 0):
@@ -157,30 +171,66 @@ def bbm_rows_product_precoded(a_s, mag, neg, *, wl: int, vbl: int, kind: int,
     a2 = a_s << 1 if multiply_free else None
     prod = None
     for r in range(num_pp_rows(wl)):
-        m_r = mag[r]
-        s_r = neg[r]
+        rows = _booth_row(a_s, a2, mag[r], neg[r], kind=kind,
+                          multiply_free=multiply_free)
         m = max(0, vbl - 2 * r)           # bits nullified in this row
-        if kind == 0:
-            if multiply_free:
-                pos = jnp.where(m_r == 2, a2, jnp.where(m_r == 1, a_s, 0))
-                rows = jnp.where(s_r == 1, -pos, pos)
-            else:
-                # fold the sign into the (small) digit plane: one full-size
-                # multiply per row, no full-size select at all
-                rows = signed_digit(m_r, s_r) * a_s
-            contrib = (rows >> m) << m    # floor for two's complement
-        else:
-            if multiply_free:
-                pos = jnp.where(m_r == 2, a2, jnp.where(m_r == 1, a_s, 0))
-            else:
-                pos = m_r * a_s
-            rows = jnp.where(s_r == 1, -pos - 1, pos)
-            contrib = (rows >> m) << m
-            if m == 0:                    # S dot survives only at m == 0
-                contrib = contrib + s_r
+        contrib = (rows >> m) << m        # floor for two's complement
+        if kind == 1 and m == 0:          # S dot survives only at m == 0
+            contrib = contrib + neg[r]
         term = contrib << (2 * r)
         prod = term if prod is None else prod + term
     return prod
+
+
+def bbm_rows_product_scaled(a_s, mag, neg, *, wl: int, vbl: int, kind: int,
+                            multiply_free: bool | None = None):
+    """``bbm_rows_product_precoded(...) >> vbl``, formed at that scale.
+
+    Every Broken-Booth product is a multiple of ``2^vbl`` (row r < R is
+    floored to a multiple of ``2^(m_r + 2r) = 2^vbl``, and every row above
+    sits at ``4^r >= 2^vbl``), so the product divided by ``2^vbl`` is an
+    exact integer: row r < R contributes its row value floored by
+    ``m_r`` bits, unshifted, and row r >= R its row value (with Type1's S
+    dot) shifted up by ``2r - vbl``.  That drops the ``<< m`` and
+    ``<< 2r`` of each truncated row and keeps a sum of ``2^(2wl-1-vbl)``
+    magnitudes in int32 — the scale the dot form's ``_dot_scaled``
+    accumulates at, so a K-sum of these equals its partial bit for bit.
+    Same operands, broadcasting and ``multiply_free`` rule as
+    ``bbm_rows_product_precoded``.
+    """
+    if multiply_free is None:
+        multiply_free = jax.default_backend() == "tpu"
+    a2 = a_s << 1 if multiply_free else None
+    prod = None
+    for r in range(num_pp_rows(wl)):
+        rows = _booth_row(a_s, a2, mag[r], neg[r], kind=kind,
+                          multiply_free=multiply_free)
+        m = vbl - 2 * r
+        if m > 0:
+            term = rows >> m
+        else:
+            if kind == 1:
+                rows = rows + neg[r]
+            term = rows << -m
+        prod = term if prod is None else prod + term
+    return prod
+
+
+def _booth_row(a_s, a2, m_r, s_r, *, kind: int, multiply_free: bool):
+    """Row r's partial product before truncation: ``d_r * a_s``, or for
+    Type1 the one's complement ``-|d_r| * a_s - 1`` of a negative row
+    (the S dot is added back by the caller where the row keeps it)."""
+    if multiply_free:
+        pos = jnp.where(m_r == 2, a2, jnp.where(m_r == 1, a_s, 0))
+    if kind == 0:
+        if multiply_free:
+            return jnp.where(s_r == 1, -pos, pos)
+        # fold the sign into the (small) digit plane: one full-size
+        # multiply per row, no full-size select at all
+        return signed_digit(m_r, s_r) * a_s
+    if not multiply_free:
+        pos = m_r * a_s
+    return jnp.where(s_r == 1, -pos - 1, pos)
 
 
 def signed_digit(mag_r, neg_r):

@@ -45,6 +45,10 @@ ATTN_CODE_CACHE = "attn.code_cache"  # int-code cache write + attention
 LM_HEAD = "lm.head"              # final norm and the LM head
 SCOPES = (AMM_WEIGHT_DECODE, AMM_CONTRACT, AMM_STE_EXACT, ATTN_PROJ,
           ATTN_CODE_CACHE, LM_HEAD)
+# the value product over a code cache, inside ``attn.code_cache`` or
+# ``attn.latent_cache``: one scope per form, the form its shapes chose
+AMM_PV_ROWS = "amm.pv_rows"      # multiply-free Booth rows, on the VPU
+AMM_PV_DOT = "amm.pv_dot"        # the batched dot form's contractions
 # the latent-attention and expert layers' scopes (MLA + MoE families)
 ATTN_LATENT_CACHE = "attn.latent_cache"  # latent cache write + both latent
                                          # products (scores, values)

@@ -24,8 +24,10 @@ import pytest
 
 from repro.configs import AmmConfig, get_arch, reduced
 from repro.core.multipliers import MulSpec
-from repro.kernels.bbm_matmul import (bbm_matmul_coded_kblocks,
-                                      bbm_matmul_dynamic)
+from repro.kernels.bbm_matmul import (_coded_kblocks,
+                                      bbm_matmul_coded_kblocks,
+                                      bbm_matmul_dynamic, pv_form)
+from repro.kernels.booth_rows import amm_chunk_len
 from repro.kernels.ref import (AMM_BOOTH_KINDS, amm_attention_ref,
                                amm_coded_kblocks_ref,
                                amm_decode_attention_codes_ref,
@@ -416,19 +418,112 @@ def test_coded_kblocks_matches_kblocks_oracle(mul, wl, vbl, jit):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_coded_kblocks_contracts_all_blocks_at_once():
-    """The jitted PV product holds as many dots at 48 K-blocks as at one
-    (36 at wl = 16, vbl = 13, Type 0: the high-digit dot plus 7 truncated
-    rows x (digit dot + 4 residue dots)) — a per-block loop would hold
-    48 x 36."""
-    def n_dots(nb):
-        a, codes, scales = _kblock_operands(16, nb)
-        fn = jax.jit(lambda a_, c_, s_: bbm_matmul_coded_kblocks(
-            a_, c_, s_, wl=16, vbl=13, kind=0, block=16))
-        hlo = fn.lower(a, codes, scales).compile().as_text()
-        return len(re.findall(r"= \S+ dot\(", hlo))
+# (M, N) of the value product's K-blocks where it is served: qwen2-0.5b's
+# decode (7 query heads a KV head, head dim 64) and Moonlight's absorbed
+# latent attention (16 heads, the 512 value columns of the latent)
+PV_SHAPES = {"qwen2": (7, 64), "moonlight": (16, 512)}
+# the ops that form a block partial: the dot form's contractions, the rows
+# form's row arithmetic (select or multiply by backend) and block sums
+_PV_OPS = ("dot", "multiply", "select", "shift-right-arithmetic", "reduce")
 
-    assert n_dots(1) == n_dots(48) == 36
+
+def _pv_op_counts(m, n, nb, form=None):
+    """Op counts of the jitted PV product at wl = 16, vbl = 13, Type 0, in
+    the form the gate picks (``form=None``) or the one given."""
+    a, codes, scales = _kblock_operands(16, nb, m=m, n=n)
+    if form is None:
+        fn = lambda a_, c_, s_: bbm_matmul_coded_kblocks(
+            a_, c_, s_, wl=16, vbl=13, kind=0, block=16)
+    else:
+        fn = lambda a_, c_, s_: _coded_kblocks(
+            a_, c_, s_, wl=16, vbl=13, kind=0, block=16, form=form)
+    ops = re.findall(r"= \S+ ([a-z-]+)\(",
+                     jax.jit(fn).lower(a, codes, scales).compile().as_text())
+    return {op: ops.count(op) for op in _PV_OPS}
+
+
+@pytest.mark.parametrize("shape,form", [("qwen2", None), ("moonlight", None),
+                                        ("qwen2", "dot")])
+def test_coded_kblocks_contracts_all_blocks_at_once(shape, form):
+    """The jitted PV product forms every K-block at once: its ops do not
+    grow from 4 blocks to 48 (a per-block loop would hold 12 x them; below
+    3 blocks the loop that adds the blocks folds away and fusion regroups
+    the descales).  At both served shapes the gate picks the rows form,
+    with no dot at all, and every op of the row arithmetic is counted; the
+    dot form, which blocks longer than ``amm_chunk_len`` keep, holds 36
+    dots (the high-digit dot plus 7 truncated rows x (digit dot + 4
+    residue dots))."""
+    m, n = PV_SHAPES[shape]
+    if form is None:
+        assert pv_form(16, 16, 13) == "rows"
+    few, many = (_pv_op_counts(m, n, nb, form) for nb in (4, 48))
+    assert few == many
+    assert few["dot"] == (36 if form == "dot" else 0)
+
+
+def test_pv_form_at_served_shapes():
+    """The form gate, a pure function of the block's length and the
+    operating point: the served blocks of 16 positions (qwen2's (7 x
+    16)·(16 x 64) and Moonlight's (16 x 16)·(16 x 512)) take the rows form
+    at both word lengths; a block longer than ``amm_chunk_len`` keeps the
+    dot form (the rows sum over it would leave int32)."""
+    for wl, vbl in ((16, 13), (8, 5), (16, 15)):
+        assert pv_form(16, wl, vbl) == "rows"
+    assert amm_chunk_len(16, 1) < 16
+    assert pv_form(16, 16, 1) == "dot"
+    assert pv_form(amm_chunk_len(16, 13) + 1, 16, 13) == "dot"
+    assert pv_form(amm_chunk_len(16, 13), 16, 13) == "rows"
+
+
+@pytest.mark.parametrize("nb", [6, 48])
+@pytest.mark.parametrize("mul,wl,vbl", [("bbm0", 8, 5), ("bbm1", 8, 7),
+                                        ("bbm0", 16, 13), ("bbm1", 16, 15)])
+def test_coded_kblocks_latent_shape_matches_oracle(mul, wl, vbl, nb):
+    """The PV product at Moonlight's latent shape (M = 16, N = 512, blocks
+    of 16 with distinct scales: 48 as at the cell's 768 positions, and 6),
+    jitted, == the per-block scalar oracle: Moonlight's served geometry
+    beside qwen2's."""
+    a, codes, scales = _kblock_operands(wl, nb, m=16, n=512)
+    assert len(set(np.asarray(scales).tolist())) == nb
+    got = jax.jit(lambda a_, c_, s_: bbm_matmul_coded_kblocks(
+        a_, c_, s_, wl=wl, vbl=vbl, kind=AMM_BOOTH_KINDS[mul], block=16))(
+            a, codes, scales)
+    ref = amm_coded_kblocks_ref(a, codes, scales, MulSpec(mul, wl, vbl),
+                                block=16)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("nb", [4, 8])
+@pytest.mark.parametrize("mul,wl,vbl", [("bbm0", 16, 13), ("bbm1", 16, 15)])
+def test_coded_kblocks_few_blocks_match_oracle(mul, wl, vbl, nb):
+    """Jitted at a few blocks, where XLA:CPU fuses the whole block combine
+    into one loop, the PV product still == the oracle bit for bit in both
+    forms: a descale is never contracted with the next add into one
+    rounding (an FMA), which an unrolled chain of adds let it do."""
+    a, codes, scales = _kblock_operands(wl, nb)
+    ref = np.asarray(amm_coded_kblocks_ref(a, codes, scales,
+                                           MulSpec(mul, wl, vbl), block=16))
+    for form in ("rows", "dot"):
+        got = jax.jit(lambda a_, c_, s_, form=form: _coded_kblocks(
+            a_, c_, s_, wl=wl, vbl=vbl, kind=AMM_BOOTH_KINDS[mul], block=16,
+            form=form))(a, codes, scales)
+        np.testing.assert_array_equal(np.asarray(got), ref, err_msg=form)
+
+
+@pytest.mark.parametrize("shape", list(PV_SHAPES))
+@pytest.mark.parametrize("mul,wl,vbl", [("bbm0", 8, 5), ("bbm1", 16, 15),
+                                        ("bbm0", 16, 13)])
+def test_coded_kblocks_forms_agree(mul, wl, vbl, shape):
+    """Both forms of the PV product give the same bits at both served
+    shapes, jitted: the dot form stays for blocks the rows sum cannot
+    hold in int32."""
+    m, n = PV_SHAPES[shape]
+    a, codes, scales = _kblock_operands(wl, 6, m=m, n=n)
+    got = {form: np.asarray(jax.jit(
+        lambda a_, c_, s_, form=form: _coded_kblocks(
+            a_, c_, s_, wl=wl, vbl=vbl, kind=AMM_BOOTH_KINDS[mul], block=16,
+            form=form))(a, codes, scales)) for form in ("rows", "dot")}
+    np.testing.assert_array_equal(got["rows"], got["dot"])
 
 
 @pytest.mark.parametrize("mul,wl,vbl", SWEEP)

@@ -16,7 +16,9 @@ this file never loads the TPU library.  Code that picks a form from
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +26,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.dsp.fir import NUM_TAPS
-from repro.kernels.bbm_matmul import bbm_matmul_precoded, bbm_matmul_scaled
+from repro.kernels.bbm_matmul import (bbm_matmul_coded_kblocks,
+                                      bbm_matmul_precoded, bbm_matmul_scaled)
 from repro.kernels.fir_kernel import fir_bbm_bank_precoded
 from repro.kernels.flash_attention import flash_attention_amm
 from repro.kernels.quant_matmul import quant_matmul
@@ -33,6 +36,7 @@ WL, VBL = 16, 13                       # the paper's Table IV operating point
 CHANNELS, SAMPLES, TAPS = 64, 65536, NUM_TAPS
 M, D_MODEL, D_FF = 128, 896, 4864      # qwen2-0.5b MLP, a 128-token block
 HEADS, SEQ, HEAD_DIM = 14, 4096, 64    # qwen2-0.5b attention at 4k
+SLOTS, KV_HEADS, CTX = 64, 2, 768      # the chat cell's decode step
 ROWS = WL // 2
 i32, f32 = jnp.int32, jnp.float32
 
@@ -113,3 +117,37 @@ def test_quant_matmul_compiles(on_chip):
         on_chip((M, D_MODEL), f32), on_chip((D_MODEL, D_FF), f32),
         on_chip((), f32), on_chip((), f32), on_chip((), i32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the served value products, one layer: (vmapped batch, M, N) at 768
+# positions in blocks of 16: qwen2's decode (64 slots x 2 KV heads, 7 query
+# heads a KV head, head dim 64) and Moonlight's absorbed latent attention
+# (64 slots, 16 heads, the latent's 512 value columns)
+PV_SERVED = {"qwen2": ((SLOTS, KV_HEADS), HEADS // KV_HEADS, HEAD_DIM),
+             "moonlight": ((SLOTS,), 16, 512)}
+
+
+@pytest.mark.parametrize("shape", list(PV_SERVED))
+def test_value_product_rows_form_compiles_fused(on_chip, shape):
+    """The served value products take the rows form on the chip with no
+    dot, and no array as large as a row product (batch x 48 blocks x M x
+    16 x N elements, in any layout) reaches memory: the rows and the
+    block sums fuse."""
+    batch, m, n = PV_SERVED[shape]
+    fn = lambda a, c, s: bbm_matmul_coded_kblocks(a, c, s, wl=WL, vbl=VBL,
+                                                  kind=0, block=16)
+    for _ in batch:
+        fn = jax.vmap(fn)
+    compiled = _compile(fn, on_chip(batch + (m, CTX), f32),
+                        on_chip(batch + (CTX, n), i32),
+                        on_chip(batch + (CTX // 16,), f32))
+    hlo = compiled.as_text()
+    assert " dot(" not in hlo and " convolution(" not in hlo
+    row = math.prod(batch) * (CTX // 16) * m * 16 * n
+    # the element count of every array a top-level instruction names
+    entry = hlo[hlo.index("\nENTRY"):]
+    sizes = [math.prod(int(d) for d in dims.split(",") if d)
+             for line in entry.splitlines()
+             for dims in re.findall(r"\b(?:pred|[suf]\d+|bf16)\[([\d,]*)\]",
+                                    line.split(" metadata=")[0])]
+    assert sizes and max(sizes) < row

@@ -22,6 +22,7 @@ from repro.kernels import (bbm_matmul, bbm_matmul_precoded, booth_precode,
                            min_safe_shift)
 from repro.kernels.booth_rows import (bbm_rows_product,
                                       bbm_rows_product_precoded,
+                                      bbm_rows_product_scaled,
                                       split_signed)
 from repro.launch.mesh import make_mesh
 
@@ -66,6 +67,25 @@ def test_precoded_rows_match_bbm_mul(wl, vbl, kind):
     raw = bbm_rows_product(a_s, b & ((1 << wl) - 1), wl=wl, vbl=vbl,
                            kind=kind)
     np.testing.assert_array_equal(np.asarray(raw), np.asarray(ref))
+
+
+@pytest.mark.parametrize("wl,vbl", SWEEP)
+@pytest.mark.parametrize("kind", [0, 1])
+def test_scaled_rows_are_products_over_2_vbl(wl, vbl, kind):
+    """Every Broken-Booth product is a multiple of 2^vbl, and the scaled
+    row form gives the quotient exactly, in both row-contribution forms
+    (the value product's rows form sums these in int32)."""
+    a = jnp.asarray(RNG.integers(0, 1 << wl, 4096), jnp.int32)
+    b = jnp.asarray(RNG.integers(0, 1 << wl, 4096), jnp.int32)
+    _, a_s = split_signed(a, wl)
+    mag, neg = booth_precode(b, wl)
+    ref = np.asarray(bbm_mul(a, b, wl, vbl, kind=kind)).astype(np.int64)
+    assert not (ref % (1 << vbl)).any()
+    for multiply_free in (True, False):
+        got = bbm_rows_product_scaled(a_s, mag, neg, wl=wl, vbl=vbl,
+                                      kind=kind, multiply_free=multiply_free)
+        np.testing.assert_array_equal(np.asarray(got), ref >> vbl,
+                                      err_msg=f"multiply_free={multiply_free}")
 
 
 # --------------------------------------------------------------- kernel level

@@ -83,10 +83,9 @@ def _lm(wl=16, vbl=13, layers=2):
         mode="bitexact", mul="bbm0", wl=wl, param=vbl, apply_to="all"))
 
 
-def test_decode_program_carries_the_scopes():
-    """The serving decode step as the benchmark builds it (no plane cache,
-    int-code KV cache): each scope names some of its HLO ops."""
-    cfg = _lm()
+def _decode_scope_stacks(cfg):
+    """The name stacks of the serving decode step's HLO ops, as the
+    benchmark builds the step (no plane cache, int-code KV cache)."""
     rt = ModelRuntime.build(cfg)
     slots, max_len = 4, 2 * KV_BLOCK
     _, decode_j = make_serve_fns(cfg, rt, make_host_mesh(1, 1),
@@ -100,13 +99,40 @@ def test_decode_program_carries_the_scopes():
     hlo = decode_j.lower(params, toks, caches, pos).as_text(
         dialect="hlo", debug_info=True)
     # a name stack inside the layer scan's body is relative to it
-    stacks = [n.split("/") for n in
-              set(re.findall(r'op_name="([^"]*)"', hlo))]
+    return [n.split("/") for n in set(re.findall(r'op_name="([^"]*)"', hlo))]
+
+
+def test_decode_program_carries_the_scopes():
+    """The serving decode step as the benchmark builds it (no plane cache,
+    int-code KV cache): each scope names some of its HLO ops."""
+    stacks = _decode_scope_stacks(_lm())
     for scope in trace.SCOPES:
         assert any(scope in st for st in stacks), scope
     # the weight decode is its own scope, never inside the contraction's
     assert not any(trace.AMM_WEIGHT_DECODE in st and trace.AMM_CONTRACT in st
                    for st in stacks)
+
+
+def test_value_product_scope_sits_in_the_code_cache_scope():
+    """The decode step's value product runs under the scope of the form
+    its shapes chose (``pv_form``), inside ``attn.code_cache``; the other
+    form's scope names no op."""
+    from repro.kernels.bbm_matmul import pv_form
+    cfg = _lm()
+    stacks = _decode_scope_stacks(cfg)
+    form = pv_form(KV_BLOCK, cfg.amm.wl, cfg.amm.param)
+    taken = trace.AMM_PV_ROWS if form == "rows" else trace.AMM_PV_DOT
+    other = trace.AMM_PV_DOT if form == "rows" else trace.AMM_PV_ROWS
+
+    def depth(st, scope):
+        # a scope opened inside a vmap reads "vmap(vmap(<scope>))"
+        return next((i for i, n in enumerate(st) if scope in n), None)
+
+    inside = [st for st in stacks if depth(st, taken) is not None]
+    assert inside
+    assert all(trace.ATTN_CODE_CACHE in st[:depth(st, taken)]
+               for st in inside)
+    assert not any(depth(st, other) is not None for st in stacks)
 
 
 # ------------------------------------------- outputs with spans on and off
